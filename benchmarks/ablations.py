@@ -19,6 +19,7 @@ import json
 import os
 
 from repro.eval import EvalRunner, aggregate_by_label, make_tasks
+from repro.compile_cache import enable_compile_cache
 
 CUBE = dict(num_xpus=4096, cube_n=4)
 
@@ -82,6 +83,7 @@ def main(argv=None) -> None:
                     choices=["all"] + sorted(ARMS))
     ap.add_argument("--out", type=str, default="")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     results = {}
     for arm in (sorted(ARMS) if args.arm == "all" else [args.arm]):
         results[arm] = run_arm(arm, args.runs, args.num_jobs, args.load,

@@ -22,6 +22,7 @@ from typing import Dict
 from repro.core.allocator import make_policy
 from repro.sim.simulator import Simulator
 from repro.traces.generator import TraceConfig, generate_trace
+from repro.compile_cache import enable_compile_cache
 
 POLICIES = [
     ("firstfit_16c", "firstfit", dict(dims=(16, 16, 16))),
@@ -59,6 +60,7 @@ def main(argv=None) -> Dict:
     ap.add_argument("--skip-naive", action="store_true",
                     help="skip the slow naive-RFold baseline run")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     results: Dict = {"policies": {}, "baseline": {}}
     for scale in args.job_scales:

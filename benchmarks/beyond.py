@@ -13,6 +13,7 @@ from repro.core.allocator import make_policy
 from repro.sim.metrics import aggregate, summarize
 from repro.sim.simulator import Simulator
 from repro.traces.generator import TraceConfig, generate_trace
+from repro.compile_cache import enable_compile_cache
 
 VARIANTS = [
     ("rfold (paper FIFO)", "rfold", {}, dict(backfill=False)),
@@ -29,6 +30,7 @@ def main(argv=None):
     ap.add_argument("--load", type=float, default=1.5)
     ap.add_argument("--out", default="")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     print("variant,jcr,jct_p50,jct_p90,jct_p99,util_mean")
     results = {}
     for label, name, pkw, skw in VARIANTS:

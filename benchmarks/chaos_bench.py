@@ -30,6 +30,7 @@ import time
 from typing import Dict, List, Optional
 
 from repro.api import SCENARIOS, run_scenario
+from repro.compile_cache import enable_compile_cache
 
 # The service-bench parity matrix, reused: 512 XPUs per policy.
 POLICY_CONFIGS = [
@@ -137,6 +138,7 @@ def main(argv=None) -> None:
                          "node_churn headline")
     ap.add_argument("--out", default="BENCH_chaos.json")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     num_jobs = args.num_jobs or (60 if args.quick else 120)
     scenarios = [args.scenario] if args.scenario else sorted(SCENARIOS)

@@ -38,6 +38,7 @@ from repro.api import (SCENARIOS, Scheduler, SchedulerClient,
                        SchedulerConfig, TraceConfig, fault_schedule,
                        generate_trace, make_policy)
 from repro.serve.scheduler import protocol
+from repro.compile_cache import enable_compile_cache
 
 POLICY_KW = dict(num_xpus=512, cube_n=4)
 
@@ -171,6 +172,7 @@ def main(argv=None) -> None:
                     help="smaller stream for CI smoke")
     ap.add_argument("--out", default="BENCH_crash_loop.json")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     if args.quick:
         args.num_jobs = min(args.num_jobs, 36)
         args.kills = min(args.kills, 3)

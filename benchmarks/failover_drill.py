@@ -50,6 +50,7 @@ from typing import Dict, List, Optional, Tuple
 
 import repro
 from repro.api import Scheduler, SchedulerConfig, SchedulerClient
+from repro.compile_cache import enable_compile_cache
 from benchmarks.crash_loop import POLICY_KW, build_op_stream
 
 REPL_KW = dict(checkpoint_every=7, repl_poll=0.1,
@@ -71,7 +72,11 @@ while True:
 
 def _spawn_primary(ckpt_dir: str, script_dir: str):
     """The primary as a real OS process, so the kill is a genuine
-    ``kill -9`` — no in-process shortcuts."""
+    ``kill -9`` — no in-process shortcuts. Its config must keep the
+    default numpy engine: this parent runs a control scheduler in its
+    own process, and an accelerator belongs to one process at a time,
+    so a device engine here would have two processes contend for the
+    chip."""
     script = os.path.join(script_dir, "primary.py")
     with open(script, "w") as f:
         f.write(_PRIMARY)
@@ -356,6 +361,7 @@ def main(argv=None) -> None:
                     help="smaller stream for CI smoke")
     ap.add_argument("--out", default="BENCH_failover.json")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     if args.quick:
         args.num_jobs = min(args.num_jobs, 36)
         args.ack_n = min(args.ack_n, 20)

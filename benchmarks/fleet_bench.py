@@ -62,6 +62,7 @@ from typing import Dict
 
 from repro.eval import (EvalRunner, aggregate_by_label, fig3, fig4,
                         make_tasks, table1)
+from repro.compile_cache import enable_compile_cache
 
 # Dual headline floors: fleet mode may not slow the host path down,
 # and must beat per-sim batch-1 driving on a compiled engine by 5x.
@@ -273,6 +274,7 @@ def main(argv=None) -> Dict:
     ap.add_argument("--engine", type=str, default="jax",
                     help="batched engine for the headline section")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     runs, num_jobs = (2, 60) if args.quick else (3, 200)
     sims, rounds = (6, 80) if args.quick else (8, 120)

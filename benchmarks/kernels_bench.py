@@ -11,6 +11,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
+
 
 def _time(fn: Callable, iters: int = 5, warmup: int = 2) -> float:
     for _ in range(warmup):
@@ -91,6 +93,7 @@ def bench_simulator(emit=print) -> None:
 
 
 def main(emit=print) -> None:
+    enable_compile_cache()
     emit("name,us_per_call,derived")
     bench_fitmask(emit)
     bench_allocator(emit)

@@ -20,6 +20,7 @@ from typing import Dict
 
 from repro.api import (PAPER_TABLE1, EvalRunner, aggregate_by_label, fig3,
                        fig4, make_tasks, table1)
+from repro.compile_cache import enable_compile_cache
 
 # Policy matrix as evaluated by the paper.
 TABLE1_CONFIGS = [
@@ -184,6 +185,7 @@ def main(argv=None) -> None:
                          "--ckpt-dir when checkpointing alongside the "
                          "healthy sweep")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     if args.scenario:
         from repro.sim.scenarios import SCENARIOS
         if args.scenario not in SCENARIOS:

@@ -21,6 +21,7 @@ from typing import Dict
 from repro.core.allocator import make_policy
 from repro.sim.simulator import Simulator
 from repro.traces.generator import TraceConfig, generate_trace
+from repro.compile_cache import enable_compile_cache
 
 CUBE_SIZES = (8, 4, 2)
 
@@ -47,6 +48,7 @@ def main(argv=None) -> Dict:
     ap.add_argument("--quick", action="store_true",
                     help="CI-sized run (60 jobs)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     num_jobs = 60 if args.quick else args.num_jobs
 
     results: Dict = {"config": {"num_jobs": num_jobs, "seed": args.seed,

@@ -51,6 +51,7 @@ import numpy as np
 from repro.api import (Scheduler, SchedulerConfig, Simulator, TraceConfig,
                        generate_trace, make_policy, summarize)
 from repro.serve.scheduler import PLACED, QUEUED, REJECTED, AllocatorCore
+from repro.compile_cache import enable_compile_cache
 
 OVERHEAD_THRESHOLD_MS = 25.0
 
@@ -230,6 +231,7 @@ def main(argv=None) -> None:
                     help="max p99 service overhead vs in-process")
     ap.add_argument("--out", default="BENCH_service.json")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     parity_jobs = 50 if args.quick else 120
     latency_jobs = 150 if args.quick else 500

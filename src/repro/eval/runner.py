@@ -361,7 +361,10 @@ class EvalRunner:
     """Fan tasks across a process pool, checkpointing each result.
 
     ``workers=None`` uses ``os.cpu_count()``; ``workers <= 1`` runs
-    inline (no pool) — useful for tests and debugging. With
+    inline (no pool) — useful for tests and debugging. A JAX-backed
+    engine (anything but ``numpy``) runs only inline: an accelerator
+    belongs to one process at a time, so ``run`` raises rather than
+    fork workers for one. With
     ``checkpoint_dir`` set, completed tasks are skipped on re-run when
     their stored fingerprint matches the requested configuration;
     mismatching or unreadable checkpoints are ignored and re-executed.
@@ -470,6 +473,22 @@ class EvalRunner:
             return max(2, min(8, -(-n_pending // (4 * workers))))
         return int(fs)
 
+    def _uses_device_engine(self, tasks: Sequence[EvalTask],
+                            pending: Sequence[int],
+                            fleet_size: Optional[int]) -> bool:
+        """Whether the pending work would run a JAX-backed fitmask
+        engine: the brokers' engine in fleet mode, each task's own
+        engine selection on the per-task path."""
+        from repro.core.engineconfig import EngineConfig
+        if fleet_size:
+            selections = [self.fleet_engine]
+        else:
+            selections = [tasks[i].policy_kw.get(
+                "engine", tasks[i].policy_kw.get("fitmask_engine"))
+                for i in pending]
+        return any(EngineConfig.coerce(s).resolve_name() != "numpy"
+                   for s in selections)
+
     def run(self, tasks: Sequence[EvalTask]) -> List[Dict]:
         """Run the matrix; returns records ordered like ``tasks``."""
         t0 = time.perf_counter()
@@ -487,6 +506,12 @@ class EvalRunner:
                       "from checkpoints")
 
         fleet_size = self._resolve_fleet_size(len(pending))
+        if (pending and self.workers and self.workers > 1
+                and self._uses_device_engine(tasks, pending, fleet_size)):
+            raise ValueError(
+                "a device fitmask engine runs in this process only: an "
+                "accelerator belongs to one process at a time, so forked "
+                "workers cannot share it; run with workers=0 or 1")
         if pending and fleet_size:
             self._run_fleets(tasks, pending, records, fleet_size)
         elif pending:

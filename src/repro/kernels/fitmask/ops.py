@@ -12,7 +12,7 @@ behind one registry and the allocator picks at runtime:
     accelerator path and the apples-to-apples baseline for the kernel.
   * ``pallas`` — the Pallas TPU kernel: one VMEM integral-image pass
     per grid answering all K candidate boxes
-    (`kernel.fitmask_multibox`); interpret mode off-TPU.
+    (`kernel.fitmask_multibox`); interpreted on the CPU backend.
   * ``ref``    — `jax.lax.reduce_window` oracle.
 
 Selection: an explicit ``engine=`` argument wins, then
@@ -53,6 +53,23 @@ BUCKET_CACHE_SIZE = 64    # distinct fused (box-table, grid) programs
 
 def _canon_boxes(boxes: Sequence[Box]) -> Tuple[Box, ...]:
     return tuple(tuple(int(v) for v in b) for b in boxes)  # type: ignore
+
+
+def _window_fits(ii, box: Box):
+    """Cropped (..., X-a+1, Y-b+1, Z-c+1) int32 fit mask for one box
+    from a prebuilt integral image over the trailing 3 axes (leading
+    axes are batch dims). Nested per-axis differencing — three
+    slice-subtractions — is algebraically the 8-corner
+    inclusion/exclusion at less than half the op count."""
+    a, b, c = box
+    s = ii[..., a:, :, :] - ii[..., :-a, :, :]
+    s = s[..., b:, :] - s[..., :-b, :]
+    s = s[..., c:] - s[..., :-c]
+    return (s == 0).astype(np.int32)
+
+
+def _pow2(n: int) -> int:
+    return 1 << max(n - 1, 0).bit_length()
 
 
 class FitmaskEngine:
@@ -167,7 +184,6 @@ class JaxEngine(FitmaskEngine):
     def _window_fn(box: Box):
         import jax
         import jax.numpy as jnp
-        from .kernel import _window_fits
         a, b, c = box
 
         def window(ii):
@@ -276,46 +292,65 @@ class JaxEngine(FitmaskEngine):
         return self._free_counts_fn()(jnp.asarray(occ))
 
 
+def pallas_interpret() -> bool:
+    """Whether the Pallas kernels run in interpret mode — the one place
+    that decides it, from JAX's default backend: compiled on a TPU,
+    interpreted on the CPU (tests and rehearsals), and refused on any
+    other backend rather than silently interpreted there."""
+    import jax
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(
+        f"the Pallas fitmask kernels compile for a TPU (or run "
+        f"interpreted on the CPU backend); JAX's backend is {backend!r}")
+
+
+def _pad_grids(occ: np.ndarray) -> np.ndarray:
+    """Pad the grid axis with empty grids up to a power of two."""
+    bsz = occ.shape[0]
+    if _pow2(bsz) == bsz:
+        return occ
+    pad = np.zeros((_pow2(bsz) - bsz,) + occ.shape[1:], occ.dtype)
+    return np.concatenate([occ, pad])
+
+
 class PallasEngine(FitmaskEngine):
     """The multi-box Pallas kernel: one VMEM pass for all K boxes,
-    compiled on TPU, interpret mode elsewhere. ``multibox`` is already
-    a single static-box-table program, so the default
-    ``multibox_bucketed`` (multibox + free_counts) is two dispatches —
-    both shape-stable under the broker's bucketed padding, hence
-    ``pads_shapes``."""
+    compiled on a TPU, interpreted on the CPU (:func:`pallas_interpret`).
+    The kernel reads its boxes as data, so a program is compiled per
+    (B, K, grid) shape; both calls pad B (with empty grids) and K (with
+    repeats of the last box) up to powers of two and slice the answer
+    back on the host, which keeps the served path — whose B and K
+    change every occupancy epoch — to a handful of programs. The
+    default ``multibox_bucketed`` (multibox + free_counts) is two
+    dispatches, shape-stable under the broker's bucketed padding,
+    hence ``pads_shapes``."""
 
     name = "pallas"
     pads_shapes = True
 
-    def __init__(self, interpret: Optional[bool] = None):
-        self._interpret = interpret
-
-    def _interp(self) -> bool:
-        if self._interpret is not None:
-            return self._interpret
-        import jax
-        return jax.default_backend() != "tpu"
-
-    def multibox(self, occ, boxes: Sequence[Box]):
-        import jax.numpy as jnp
+    def multibox(self, occ, boxes: Sequence[Box]) -> np.ndarray:
         from . import kernel as _kernel
-        return _kernel.fitmask_multibox(jnp.asarray(occ),
-                                        _canon_boxes(boxes),
-                                        interpret=self._interp())
+        occ = np.asarray(occ)
+        boxes = _canon_boxes(boxes)
+        bsz, k = occ.shape[0], len(boxes)
+        if not k:
+            return np.zeros((bsz, 0) + occ.shape[1:], np.int32)
+        table = boxes + boxes[-1:] * (_pow2(k) - k)
+        out = _kernel.fitmask_multibox(_pad_grids(occ), table,
+                                       interpret=pallas_interpret())
+        return np.asarray(out)[:bsz, :k]
 
-    def fitmask(self, occ, box: Box):
-        import jax.numpy as jnp
+    def free_counts(self, occ) -> np.ndarray:
         from . import kernel as _kernel
-        return _kernel.fitmask_batched(jnp.asarray(occ),
-                                       tuple(int(v) for v in box),
-                                       interpret=self._interp())
-
-    def free_counts(self, occ):
-        import jax.numpy as jnp
-        from . import kernel as _kernel
-        occ = jnp.asarray(occ)
-        n3 = occ.shape[1] * occ.shape[2] * occ.shape[3]
-        return n3 - _kernel.occupancy_counts(occ, interpret=self._interp())
+        occ = np.asarray(occ)
+        bsz, n3 = occ.shape[0], int(np.prod(occ.shape[1:]))
+        used = _kernel.occupancy_counts(_pad_grids(occ),
+                                        interpret=pallas_interpret())
+        return n3 - np.asarray(used)[:bsz]
 
 
 class RefEngine(FitmaskEngine):

@@ -168,12 +168,9 @@ def probe_depths(cfg: ModelConfig):
 
 
 def _cost_dict(compiled):
-    """Normalize ``Compiled.cost_analysis()`` across jax versions: older
-    releases return a one-element list of dicts, newer ones the dict."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost or {}
+    """``Compiled.cost_analysis()`` as a dict (it returns ``None`` when
+    the backend has no cost model)."""
+    return compiled.cost_analysis() or {}
 
 
 def _compile_cost(cfg: ModelConfig, shape: InputShape, multi_pod: bool):

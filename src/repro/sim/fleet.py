@@ -76,8 +76,9 @@ fleet dies in practice.
     (:data:`repro.core.engineconfig.FAILOVER_CHAIN`), adopting the
     first backend that answers (``engine_failovers`` /
     ``failover_engine``) and resetting its compiled-shape bucket
-    state. The first few post-failover multibox flushes are
-    canary-checked against the host numpy oracle
+    state. The first failover of a broker raises a ``RuntimeWarning``
+    carrying the original exception. The first few post-failover
+    multibox flushes are canary-checked against the host numpy oracle
     (``canary_checks``/``canary_mismatches``) — answers are a pure
     function of the inputs, so any mismatch is a real defect, not
     noise. Failover applies only to registry-named engines; a custom
@@ -92,6 +93,7 @@ import hashlib
 import math
 import threading
 import time
+import warnings
 from collections import OrderedDict
 from dataclasses import dataclass
 from typing import (Any, Callable, Dict, List, Optional, Sequence,
@@ -305,6 +307,7 @@ class QueryBroker(MaskQueryClient):
         self._watched: List[threading.Thread] = []  # stepper threads
         self._faults_left = 0        # armed synthetic engine failures
         self._canary_left = 0        # post-failover parity checks due
+        self._failover_warned = False
         self.stats = BrokerStats()
 
     # -- simulator lifecycle ------------------------------------------
@@ -620,6 +623,22 @@ class QueryBroker(MaskQueryClient):
             self.stats.failover_engine = name
         return True
 
+    def _warn_failover(self, exc: BaseException,
+                       names: Tuple[str, ...]) -> None:
+        """Say, once per broker, that the configured engine is being
+        left and why: a failover keeps the answers right, but it also
+        moves the work off the device, which the counters alone do not
+        make visible to whoever reads the run's output."""
+        with self._lock:
+            if self._failover_warned:
+                return
+            self._failover_warned = True
+            name = self.engine_name
+        warnings.warn(
+            f"fitmask engine {name!r} failed its retry with "
+            f"{type(exc).__name__}: {exc}; the query broker fails over "
+            f"to {' -> '.join(names)}", RuntimeWarning, stacklevel=3)
+
     def _engine_call(self, kind: str, occ: np.ndarray,
                      boxes: Optional[Tuple[Box, ...]] = None):
         """Engine invocation with containment: retry once on the same
@@ -635,7 +654,10 @@ class QueryBroker(MaskQueryClient):
                 if attempt == 0:
                     with self._lock:
                         self.stats.engine_retries += 1
-        for name in self._failover_names():
+        names = self._failover_names()
+        if names:
+            self._warn_failover(last, names)
+        for name in names:
             if not self._adopt_engine(name):
                 continue
             try:

@@ -249,6 +249,19 @@ def test_checkpoint_name_is_filesystem_safe():
     assert name.endswith(f"__{t.fingerprint()}.json")
 
 
+@pytest.mark.parametrize("fleet_size", ["auto", 0])
+def test_device_engine_refuses_worker_processes(fleet_size):
+    """A JAX-backed engine claims the accelerator, which belongs to one
+    process: the runner raises instead of forking workers for it."""
+    runner = EvalRunner(workers=2, fleet_size=fleet_size,
+                        fleet_engine="pallas")
+    tasks = make_tasks([("RFold (4^3)", "rfold",
+                         dict(num_xpus=512, cube_n=4, engine="pallas"))],
+                       runs=2, num_jobs=5, load=1.5, seed0=0)
+    with pytest.raises(ValueError, match="one process"):
+        runner.run(tasks)
+
+
 def test_workers_default_is_cpu_count():
     assert EvalRunner().workers == os.cpu_count()
 

@@ -70,6 +70,21 @@ def test_set_default_engine_overrides_env(monkeypatch):
 
 
 # ------------------------------------------------------- parity
+@pytest.mark.parametrize("backend,interpret", [
+    ("tpu", False), ("cpu", True), ("gpu", None)])
+def test_pallas_interpret_follows_the_backend(monkeypatch, backend,
+                                              interpret):
+    """Interpret mode is chosen in one place: compiled on a TPU,
+    interpreted on the CPU, refused on any other backend."""
+    import jax
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    if interpret is None:
+        with pytest.raises(RuntimeError, match="gpu"):
+            ops.pallas_interpret()
+    else:
+        assert ops.pallas_interpret() is interpret
+
+
 def test_all_engines_agree_on_free_counts():
     occ = _occ(seed=7)
     ref = np.asarray(ops.get_engine("numpy").free_counts(occ))

@@ -117,8 +117,6 @@ with mesh:
                       out_shardings=(ps, os_, None)).lower(params, opt, bs)
     compiled = lowered.compile()
     cost = compiled.cost_analysis()
-if isinstance(cost, (list, tuple)):
-    cost = cost[0] if cost else {}
 print(json.dumps({"flops": cost.get("flops", -1),
                   "devices": len(jax.devices())}))
 """
