@@ -18,6 +18,8 @@ from __future__ import annotations
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Tuple
 
+from repro import obs
+
 from .folding import Fold, enumerate_folds, fold_links, verify_fold
 from .geometry import Coord, Dims, JobShape, is_torus_neighbor, volume
 from .reconfig import ReconfigPlan, ReconfigTorus
@@ -182,23 +184,33 @@ class FirstFitPolicy(_StaticBase):
                               engine=self.torus.engine_config)
 
     def try_place(self, job_id: int, shape: JobShape) -> Optional[Placement]:
-        folds = [f for f in enumerate_folds(shape,
-                                            max_dim=max(self.torus.dims),
-                                            include_identity=True)
-                 if f.kind == "identity"]
-        self.torus.prefetch_boxes(self._candidate_boxes(folds))
-        for fold in folds:
-            if any(b > d for b, d in zip(fold.box, self.torus.dims)):
-                continue
-            origin = self.torus.find_free_box(fold.box)
-            if origin is None:
-                continue
-            wrap = self._wrap_for_box(fold.box, origin)
-            ok, broken = verify_fold(fold, wrap)
-            if not ok:
-                continue
-            return self._commit_fold(job_id, fold, origin, tuple(broken))
-        return None
+        with obs.span("plan.search") as sp:
+            folds = [f for f in enumerate_folds(shape,
+                                                max_dim=max(self.torus.dims),
+                                                include_identity=True)
+                     if f.kind == "identity"]
+            self.torus.prefetch_boxes(self._candidate_boxes(folds))
+            placement = None
+            visited = 0
+            for fold in folds:
+                if any(b > d for b, d in zip(fold.box, self.torus.dims)):
+                    continue
+                visited += 1
+                origin = self.torus.find_free_box(fold.box)
+                if origin is None:
+                    continue
+                wrap = self._wrap_for_box(fold.box, origin)
+                ok, broken = verify_fold(fold, wrap)
+                if not ok:
+                    continue
+                placement = self._commit_fold(job_id, fold, origin,
+                                              tuple(broken))
+                break
+            if sp.recording:
+                sp.tag(policy=self.name, folds=visited,
+                       pruned=len(folds) - visited,
+                       placed=placement is not None)
+            return placement
 
 
 class FoldingPolicy(_StaticBase):
@@ -212,26 +224,32 @@ class FoldingPolicy(_StaticBase):
                              engine=self.torus.engine_config)
 
     def try_place(self, job_id: int, shape: JobShape) -> Optional[Placement]:
-        candidates = []
-        folds = list(enumerate_folds(shape, max_dim=max(self.torus.dims)))
-        self.torus.prefetch_boxes(self._candidate_boxes(folds))
-        for fold in folds:
-            if any(b > d for b, d in zip(fold.box, self.torus.dims)):
-                continue
-            origin = self.torus.find_free_box(fold.box)
-            if origin is None:
-                continue
-            wrap = self._wrap_for_box(fold.box, origin)
-            ok, broken = verify_fold(fold, wrap)
-            if not ok:
-                continue
-            score = (len(broken), max(fold.box), volume(fold.box))
-            candidates.append((score, fold, origin, tuple(broken)))
-        if not candidates:
-            return None
-        candidates.sort(key=lambda t: t[0])
-        _, fold, origin, broken = candidates[0]
-        return self._commit_fold(job_id, fold, origin, broken)
+        with obs.span("plan.search") as sp:
+            candidates = []
+            folds = list(enumerate_folds(shape, max_dim=max(self.torus.dims)))
+            self.torus.prefetch_boxes(self._candidate_boxes(folds))
+            visited = 0
+            for fold in folds:
+                if any(b > d for b, d in zip(fold.box, self.torus.dims)):
+                    continue
+                visited += 1
+                origin = self.torus.find_free_box(fold.box)
+                if origin is None:
+                    continue
+                wrap = self._wrap_for_box(fold.box, origin)
+                ok, broken = verify_fold(fold, wrap)
+                if not ok:
+                    continue
+                score = (len(broken), max(fold.box), volume(fold.box))
+                candidates.append((score, fold, origin, tuple(broken)))
+            if sp.recording:
+                sp.tag(policy=self.name, folds=visited,
+                       pruned=len(folds) - visited, placed=bool(candidates))
+            if not candidates:
+                return None
+            candidates.sort(key=lambda t: t[0])
+            _, fold, origin, broken = candidates[0]
+            return self._commit_fold(job_id, fold, origin, broken)
 
 
 # ----------------------------------------------------------------------
@@ -284,28 +302,36 @@ class _ReconfigBase(PlacementPolicy):
     use_naive = False
 
     def try_place(self, job_id: int, shape: JobShape) -> Optional[Placement]:
-        if self.use_naive:
-            best: Optional[ReconfigPlan] = None
-            for fold in self._folds(shape):
-                plan = self.cluster.place_fold_naive(
-                    fold, offset_search=self.offset_search)
-                if plan is None:
-                    continue
-                if best is None or plan.score() < best.score():
-                    best = plan
-        elif shape.size > self.cluster.free_xpus:
-            best = None  # every fold box has volume == job size
-        else:
-            # The batched plan-search engine: fold-level bound pruning
-            # plus the per-fold pre-scored offset tables, all inside
-            # the cluster model (repro.core.reconfig.plan_search).
-            best = self.cluster.plan_search(
-                self._folds(shape), offset_search=self.offset_search)
-        if best is None:
-            return None
-        self.cluster.commit(job_id, best)
-        meta = dict(self.cluster.alloc_meta[job_id])
-        return Placement(job_id, shape, best.broken_rings, meta)
+        with obs.span("plan.search") as sp:
+            visited = pruned = 0
+            if self.use_naive:
+                best: Optional[ReconfigPlan] = None
+                for fold in self._folds(shape):
+                    visited += 1
+                    plan = self.cluster.place_fold_naive(
+                        fold, offset_search=self.offset_search)
+                    if plan is None:
+                        continue
+                    if best is None or plan.score() < best.score():
+                        best = plan
+            elif shape.size > self.cluster.free_xpus:
+                best = None  # every fold box has volume == job size
+            else:
+                # The batched plan-search engine: fold-level bound
+                # pruning plus the per-fold pre-scored offset tables,
+                # all inside the cluster model
+                # (repro.core.reconfig.plan_search).
+                best = self.cluster.plan_search(
+                    self._folds(shape), offset_search=self.offset_search)
+                visited, pruned = self.cluster.last_search
+            if sp.recording:
+                sp.tag(policy=self.name, folds=visited, pruned=pruned,
+                       placed=best is not None)
+            if best is None:
+                return None
+            self.cluster.commit(job_id, best)
+            meta = dict(self.cluster.alloc_meta[job_id])
+            return Placement(job_id, shape, best.broken_rings, meta)
 
     def _can_ever_place(self, shape: JobShape) -> bool:
         """Empty-cluster feasibility without a clone or placement: a

@@ -297,6 +297,9 @@ class ReconfigTorus:
         self.occ = np.zeros((self.num_cubes,) + (cube_n,) * 3, dtype=bool)
         # cube dedicated to a multi-cube job's virtual torus (-1 = no)
         self.dedicated = np.full(self.num_cubes, -1, dtype=np.int64)
+        # (folds visited, folds pruned by the bound) of the last
+        # plan_search: tags of the caller's ``plan.search`` span.
+        self.last_search: Tuple[int, int] = (0, 0)
         self.allocations: Dict[int, List[Piece]] = {}
         self.alloc_meta: Dict[int, dict] = {}
         # Fault state (chaos layer): failed cells are marked busy in
@@ -752,9 +755,12 @@ class ReconfigTorus:
         best: Optional[ReconfigPlan] = None
         bound: Optional[Tuple] = None
         n = self.cube_n
+        visited = pruned = 0
         for fold in folds:
             if bound is not None and fold_score_bound(fold, n) >= bound:
+                pruned += 1
                 continue  # cannot strictly beat the incumbent
+            visited += 1
             plan = self.place_fold(fold, offset_search=offset_search,
                                    bound=bound)
             if plan is None:
@@ -762,6 +768,7 @@ class ReconfigTorus:
             if bound is None or plan.score() < bound:
                 best = plan
                 bound = plan.score()
+        self.last_search = (visited, pruned)
         return best
 
     def place_fold_naive(self, fold: Fold,

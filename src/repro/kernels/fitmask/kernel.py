@@ -128,6 +128,7 @@ def _fitmask_multibox(occ: jnp.ndarray, table: jnp.ndarray, *,
             scratch_shapes=[pltpu.VMEM((x + 1, yp, zp), jnp.int32)]),
         out_shape=jax.ShapeDtypeStruct((bsz, k, x, y, z), jnp.int32),
         interpret=interpret,
+        name="fitmask_multibox",
     )(table.astype(jnp.int32), occ.astype(jnp.int32))
 
 
@@ -190,6 +191,7 @@ def occupancy_counts(occ: jnp.ndarray, *, interpret: bool) -> jnp.ndarray:
         out_specs=pl.BlockSpec((1, 1, _LANES), lambda i: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((bsz, 1, _LANES), jnp.int32),
         interpret=interpret,
+        name="occupancy_counts",
     )(occ.astype(jnp.int32))
     return out[:, 0, 0]
 

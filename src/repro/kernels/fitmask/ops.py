@@ -23,7 +23,10 @@ padded to the full grid (0 where the box overhangs or cannot fit), so
 callers never special-case engine, K, or infeasible boxes — plus
 ``free_counts(occ) -> (B,)`` (free cells per grid), which the
 reconfigurable torus uses for best-fit cube ordering so accelerator
-runs never rebuild the host integral image.
+runs never rebuild the host integral image. The ``jax`` and ``pallas``
+engines answer with host numpy arrays, copied back inside the engine,
+so that a profiled run can split each device call into launch, wait
+and copy (:func:`_on_device`, ``repro.obs``).
 """
 from __future__ import annotations
 
@@ -32,6 +35,7 @@ from typing import Dict, Optional, Sequence, Tuple, Type
 
 import numpy as np
 
+from repro import obs
 from repro.core import engineconfig as _engineconfig
 from repro.core import fitmask as np_engine
 
@@ -72,6 +76,47 @@ def _pow2(n: int) -> int:
     return 1 << max(n - 1, 0).bit_length()
 
 
+def _on_device(engine: "FitmaskEngine", kind: str, inputs: tuple, launch,
+               **shape):
+    """Run one device call and copy its answer to the host.
+
+    ``launch()`` dispatches the program and returns its device
+    array(s); the answer comes back as numpy, one array or a tuple as
+    launched. ``inputs`` are the call's input shapes, so the
+    ``engine.call`` span can say whether this engine is called with
+    them for ``kind`` for the first time (``new_shape``): such a call
+    compiles its program or loads it from the compile cache. Spans:
+    ``engine.call`` around ``engine.launch`` (padding already done,
+    host-to-device copy, dispatch), ``engine.wait`` (the device's own
+    time, waited for only while recording) and ``engine.fetch`` (the
+    device-to-host copy, tagged with its bytes). ``shape`` (real and
+    padded B and K) goes on ``engine.call``. With nothing recording,
+    this is the launch and one ``np.asarray`` per array."""
+    program = (kind, inputs)
+    new = program not in engine._programs
+    if new:
+        engine._programs.add(program)
+    with obs.span("engine.call") as call:
+        with obs.span("engine.launch"):
+            out = launch()
+        if call.recording:
+            import jax
+            with obs.span("engine.wait"):
+                jax.block_until_ready(out)
+        with obs.span("engine.fetch") as fetch:
+            if isinstance(out, tuple):
+                host = tuple(np.asarray(o) for o in out)
+                nbytes = sum(h.nbytes for h in host)
+            else:
+                host = np.asarray(out)
+                nbytes = host.nbytes
+            if fetch.recording:
+                fetch.tag(bytes=nbytes)
+        if call.recording:
+            call.tag(engine=engine.name, kind=kind, new_shape=new, **shape)
+    return host
+
+
 class FitmaskEngine:
     """One fitmask backend. Subclasses implement :meth:`multibox` and
     :meth:`free_counts`; :meth:`fitmask` is the single-box convenience
@@ -93,6 +138,10 @@ class FitmaskEngine:
     name = "base"
     pads_shapes = False
     host_free = False
+
+    def __init__(self) -> None:
+        # Input shapes of the device programs called so far.
+        self._programs: set = set()
 
     def multibox(self, occ, boxes: Sequence[Box]):
         """(B, X, Y, Z) x K boxes -> (B, K, X, Y, Z) int32."""
@@ -200,12 +249,17 @@ class JaxEngine(FitmaskEngine):
     def multibox(self, occ, boxes: Sequence[Box]):
         import jax.numpy as jnp
         boxes = _canon_boxes(boxes)
-        occ = jnp.asarray(occ)
         if not boxes:
             bsz, x, y, z = occ.shape
             return jnp.zeros((bsz, 0, x, y, z), jnp.int32)
-        ii = self._ii_fn()(occ)
-        return jnp.stack([self._window_fn(b)(ii) for b in boxes], axis=1)
+
+        def launch():
+            ii = self._ii_fn()(jnp.asarray(occ))
+            return jnp.stack([self._window_fn(b)(ii) for b in boxes],
+                             axis=1)
+        b = occ.shape[0]
+        return _on_device(self, "multibox", (occ.shape, len(boxes)), launch,
+                          b=b, b_pad=b, k=len(boxes), k_pad=len(boxes))
 
     @staticmethod
     @functools.lru_cache(maxsize=BUCKET_CACHE_SIZE)
@@ -268,12 +322,15 @@ class JaxEngine(FitmaskEngine):
     def multibox_bucketed(self, occ, boxes: Sequence[Box]):
         import jax.numpy as jnp
         boxes = _canon_boxes(boxes)
-        occ = jnp.asarray(occ)
         if not boxes:
             bsz, x, y, z = occ.shape
             return (jnp.zeros((bsz, 0, x, y, z), jnp.bool_),
                     self.free_counts(occ))
-        return self._bucket_fn(boxes)(occ)
+        # The broker has padded B and K already.
+        b = occ.shape[0]
+        return _on_device(self, "multibox_bucketed", (occ.shape, len(boxes)),
+                          lambda: self._bucket_fn(boxes)(jnp.asarray(occ)),
+                          b=b, b_pad=b, k=len(boxes), k_pad=len(boxes))
 
     @staticmethod
     @functools.cache
@@ -289,7 +346,10 @@ class JaxEngine(FitmaskEngine):
 
     def free_counts(self, occ):
         import jax.numpy as jnp
-        return self._free_counts_fn()(jnp.asarray(occ))
+        b = occ.shape[0]
+        return _on_device(self, "free_counts", (occ.shape,),
+                          lambda: self._free_counts_fn()(jnp.asarray(occ)),
+                          b=b, b_pad=b)
 
 
 def pallas_interpret() -> bool:
@@ -340,17 +400,25 @@ class PallasEngine(FitmaskEngine):
         if not k:
             return np.zeros((bsz, 0) + occ.shape[1:], np.int32)
         table = boxes + boxes[-1:] * (_pow2(k) - k)
-        out = _kernel.fitmask_multibox(_pad_grids(occ), table,
-                                       interpret=pallas_interpret())
-        return np.asarray(out)[:bsz, :k]
+        grids = _pad_grids(occ)
+        out = _on_device(
+            self, "multibox", (grids.shape, len(table)),
+            lambda: _kernel.fitmask_multibox(grids, table,
+                                             interpret=pallas_interpret()),
+            b=bsz, b_pad=grids.shape[0], k=k, k_pad=len(table))
+        return out[:bsz, :k]
 
     def free_counts(self, occ) -> np.ndarray:
         from . import kernel as _kernel
         occ = np.asarray(occ)
         bsz, n3 = occ.shape[0], int(np.prod(occ.shape[1:]))
-        used = _kernel.occupancy_counts(_pad_grids(occ),
-                                        interpret=pallas_interpret())
-        return n3 - np.asarray(used)[:bsz]
+        grids = _pad_grids(occ)
+        used = _on_device(
+            self, "free_counts", (grids.shape,),
+            lambda: _kernel.occupancy_counts(grids,
+                                             interpret=pallas_interpret()),
+            b=bsz, b_pad=grids.shape[0])
+        return n3 - used[:bsz]
 
 
 class RefEngine(FitmaskEngine):
@@ -422,8 +490,9 @@ def fitmask(occ, box: Box, engine: Optional[str] = None):
     """occ: (B, X, Y, Z) or (X, Y, Z). Returns the int32 fit mask of
     the same (batched) shape. ``engine=None`` follows the registry's
     selection order (set_default_engine > env var > numpy). The numpy
-    engine returns a numpy array — no device round-trip; callers that
-    want a jax array either convert or pick a jax-backed engine."""
+    engine returns a numpy array — no device round-trip; the ``jax``
+    and ``pallas`` engines copy their answer back to the host too, so
+    callers that want a jax array convert (or pick ``ref``)."""
     squeeze = occ.ndim == 3
     if squeeze:
         occ = occ[None]

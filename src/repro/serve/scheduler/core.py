@@ -52,6 +52,7 @@ from collections import OrderedDict
 from dataclasses import asdict, dataclass, field
 from typing import Any, Dict, List, Optional, Tuple
 
+from repro import obs
 from repro.core.allocator import make_policy
 from repro.core.engineconfig import EngineConfig
 from repro.core.events import TopologyEvent
@@ -283,14 +284,18 @@ class AllocatorCore:
         cfg = self.config
         if not cfg.checkpoint_dir:
             return None
-        rec = {"fingerprint": cfg.fingerprint(), "format": 1,
-               "next_id": self.next_id, "journal": self.journal}
-        save_checkpoint(cfg.checkpoint_dir, cfg, rec)
-        self._wal_writer().reset()
-        self._ops_since_sync = 0
-        return os.path.join(shard_dir(cfg.checkpoint_dir,
-                                      cfg.fingerprint()),
+        path = os.path.join(shard_dir(cfg.checkpoint_dir, cfg.fingerprint()),
                             cfg.checkpoint_name())
+        with obs.span("wal.snapshot") as sp:
+            rec = {"fingerprint": cfg.fingerprint(), "format": 1,
+                   "next_id": self.next_id, "journal": self.journal}
+            save_checkpoint(cfg.checkpoint_dir, cfg, rec)
+            self._wal_writer().reset()
+            self._ops_since_sync = 0
+            if sp.recording:
+                sp.tag(records=len(self.journal),
+                       bytes=os.path.getsize(path))
+        return path
 
     @staticmethod
     def load_state(config: SchedulerConfig) -> Optional[Dict[str, Any]]:
@@ -402,14 +407,18 @@ class AllocatorCore:
         self._current_rid = rid
         self._current_client = msg.get("client")
         before = len(self.journal)
-        try:
-            reply, events = handler(msg)
-        except Exception as e:  # noqa: BLE001 — protocol boundary
-            self._pending_topo = []
-            return {"ok": False, "error": f"{type(e).__name__}: {e}"}, []
-        finally:
-            self._current_rid = None
-            self._current_client = None
+        with obs.span("core.apply", op=op) as sp:
+            try:
+                reply, events = handler(msg)
+            except Exception as e:  # noqa: BLE001 — protocol boundary
+                self._pending_topo = []
+                return {"ok": False, "error": f"{type(e).__name__}: {e}"}, []
+            finally:
+                self._current_rid = None
+                self._current_client = None
+                if sp.recording:
+                    # Backfill included: every try_place the op ran.
+                    sp.tag(searches=sp.count("plan.search"))
         if rid is not None and len(self.journal) > before:
             self._remember(rid, reply)
         return reply, events

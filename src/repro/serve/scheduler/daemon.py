@@ -50,6 +50,8 @@ import base64
 import time
 from typing import Dict, List, Optional, Set, Tuple
 
+from repro import obs
+
 from . import protocol
 from .core import AllocatorCore, SchedulerConfig
 from .journal import decode_frames
@@ -194,14 +196,18 @@ class SchedulerDaemon:
                 line = await reader.readline()
                 if not line:
                     break
-                try:
-                    msg = protocol.decode(line)
-                except ValueError:
-                    writer.write(protocol.encode(
-                        {"ok": False, "error": "bad json"}))
-                    await writer.drain()
-                    continue
-                await self._dispatch(msg, writer)
+                # One op, from its line arriving to its reply drained.
+                with obs.span("daemon.op") as sp:
+                    try:
+                        msg = protocol.decode(line)
+                    except ValueError:
+                        writer.write(protocol.encode(
+                            {"ok": False, "error": "bad json"}))
+                        await writer.drain()
+                        continue
+                    if sp.recording:
+                        sp.tag(rid=msg.get("request_id"), op=msg.get("op"))
+                    await self._dispatch(msg, writer)
                 if self._closing.is_set():
                     break
         except (ConnectionResetError, asyncio.IncompleteReadError):

@@ -43,6 +43,8 @@ import struct
 import zlib
 from typing import Any, Dict, List, Tuple
 
+from repro import obs
+
 MAGIC = b"RPROWAL1"
 _HEADER = struct.Struct("<II")   # payload length, crc32(payload)
 
@@ -109,15 +111,20 @@ class JournalWriter:
             self._commit()
 
     def _commit(self) -> None:
-        self._f.flush()
-        if self.fsync:
-            os.fsync(self._f.fileno())
+        with obs.span("wal.fsync"):
+            self._f.flush()
+            if self.fsync:
+                os.fsync(self._f.fileno())
 
     def append(self, rec: Dict[str, Any]) -> None:
         """Frame + write + (optionally) fsync one record. On return
         the record is durable: a crash after ``append`` replays it."""
-        self._f.write(frame_record(rec))
-        self._commit()
+        with obs.span("wal.append") as sp:
+            frame = frame_record(rec)
+            self._f.write(frame)
+            self._commit()
+            if sp.recording:
+                sp.tag(bytes=len(frame))
 
     def reset(self) -> None:
         """Truncate back to an empty (header-only) journal — called

@@ -101,6 +101,7 @@ from typing import (Any, Callable, Dict, List, Optional, Sequence,
 
 import numpy as np
 
+from repro import obs
 from repro.core.maskquery import Box, MaskQueryClient
 
 # Engine-aware flush deadlines (seconds): the host engine answers a
@@ -191,7 +192,7 @@ class BrokerStats:
 
 class _Request:
     __slots__ = ("kind", "occ", "boxes", "result", "error", "done", "t",
-                 "owner")
+                 "owner", "trigger")
 
     def __init__(self, kind: str, occ: np.ndarray,
                  boxes: Optional[Tuple[Box, ...]] = None):
@@ -205,6 +206,8 @@ class _Request:
         # The submitting thread: lets the watchdog error out requests
         # a dead stepper left parked.
         self.owner = threading.current_thread()
+        # What flushed the round that answered it (set while recording).
+        self.trigger: Optional[str] = None
 
 
 class _Bucket:
@@ -332,9 +335,9 @@ class QueryBroker(MaskQueryClient):
             # watchdog must not double-decrement when it later dies.
             if cur in self._watched:
                 self._watched.remove(cur)
-            batch = self._take_round_locked(deadline_ok=True)
-        if batch is not None:
-            self._lead(batch)
+            round_ = self._take_round_locked(deadline_ok=True)
+        if round_ is not None:
+            self._lead(*round_)
 
     def _reap_locked(self) -> bool:
         """Reap watched threads that died without deactivating: shrink
@@ -395,26 +398,32 @@ class QueryBroker(MaskQueryClient):
         if req.occ.ndim != 4:
             raise ValueError("broker expects (B, X, Y, Z) occupancy, "
                              f"got shape {req.occ.shape}")
-        with self._lock:
-            self._pending.append(req)
-            self.stats.requests += 1
-            if self._inflight:
-                self.stats.requeued += 1
-            batch = self._take_round_locked(deadline_ok=False)
-        if batch is not None:
-            self._lead(batch)
-        # Park until answered; on each deadline tick, check whether a
-        # waiting round (possibly ours, possibly a successor round) is
-        # now flushable and lead it if so. With watched stepper threads
-        # the tick is bounded by the watchdog period, so a killed
-        # stepper delays a flush by at most _WATCHDOG_TICK — it can
-        # never hang the broker.
-        while not req.done.wait(self._wait_tick()):
+        # From parking to answered; the rounds this thread leads
+        # meanwhile are its ``broker.flush`` children.
+        with obs.span("broker.wait") as sp:
             with self._lock:
-                self._reap_locked()
-                batch = self._take_round_locked(deadline_ok=True)
-            if batch is not None:
-                self._lead(batch)
+                self._pending.append(req)
+                self.stats.requests += 1
+                if self._inflight:
+                    self.stats.requeued += 1
+                round_ = self._take_round_locked(deadline_ok=False)
+            if round_ is not None:
+                self._lead(*round_)
+            # Park until answered; on each deadline tick, check whether
+            # a waiting round (possibly ours, possibly a successor
+            # round) is now flushable and lead it if so. With watched
+            # stepper threads the tick is bounded by the watchdog
+            # period, so a killed stepper delays a flush by at most
+            # _WATCHDOG_TICK — it can never hang the broker.
+            while not req.done.wait(self._wait_tick()):
+                with self._lock:
+                    self._reap_locked()
+                    round_ = self._take_round_locked(deadline_ok=True)
+                if round_ is not None:
+                    self._lead(*round_)
+            if sp.recording:
+                sp.tag(kind=req.kind, grids=req.occ.shape[0],
+                       trigger=req.trigger)
         if req.error is not None:
             raise req.error
         assert req.result is not None
@@ -430,17 +439,19 @@ class QueryBroker(MaskQueryClient):
         return self.timeout
 
     # -- continuous scheduling ----------------------------------------
-    def _take_round_locked(self,
-                           deadline_ok: bool) -> Optional[List[_Request]]:
+    def _take_round_locked(
+            self, deadline_ok: bool) -> Optional[Tuple[List[_Request], str]]:
         """Decide (under the lock) whether a round flushes now; if so,
-        claim the batch and an inflight slot and return it. The caller
-        answers it outside the lock."""
+        claim the batch and an inflight slot and return it with what
+        triggered it (``all_parked``, ``quorum`` or ``timeout``). The
+        caller answers it outside the lock."""
         n = len(self._pending)
         if not n or self._inflight >= self.max_inflight:
             return None
         active = self._active
         if active <= 0 or n >= active:
             self.stats.flush_all_parked += 1
+            trigger = "all_parked"
         elif (self.quorum is not None and self.quorum < 1.0
               and n >= max(1 if self.quorum <= 0.0 else 2,
                            math.ceil(self.quorum * active))):
@@ -449,33 +460,44 @@ class QueryBroker(MaskQueryClient):
             # queries that park while a flush is live, not from timed
             # waiting (the right trade when one engine pass is cheap).
             self.stats.flush_quorum += 1
+            trigger = "quorum"
         elif (deadline_ok and self.timeout is not None
               and time.monotonic() - self._pending[0].t >= self.timeout):
             self.stats.flush_timeout += 1
+            trigger = "timeout"
         else:
             return None
         batch, self._pending = self._pending, []
         self._inflight += 1
         self.stats.flushes += 1
-        return batch
+        return batch, trigger
 
-    def _lead(self, batch: List[_Request]) -> None:
+    def _lead(self, batch: List[_Request], trigger: str) -> None:
         """Answer rounds until none is ready: the leader that finishes
         a flush immediately chains into any round that became flushable
         while it was computing (its own waiters were woken the moment
         their results landed)."""
-        while batch is not None:
-            try:
-                self._answer(batch)
-            except BaseException as e:  # noqa: BLE001 — must wake waiters
-                for r in batch:
-                    if r.result is None and r.error is None:
-                        r.error = e
+        while True:
+            with obs.span("broker.flush") as sp:
+                try:
+                    self._answer(batch)
+                except BaseException as e:  # noqa: BLE001 — must wake waiters
+                    for r in batch:
+                        if r.result is None and r.error is None:
+                            r.error = e
+                if sp.recording:
+                    sp.tag(trigger=trigger, requests=len(batch),
+                           grids=sum(r.occ.shape[0] for r in batch))
+                    for r in batch:
+                        r.trigger = trigger
             for r in batch:
                 r.done.set()
             with self._lock:
                 self._inflight -= 1
-                batch = self._take_round_locked(deadline_ok=True)
+                round_ = self._take_round_locked(deadline_ok=True)
+            if round_ is None:
+                return
+            batch, trigger = round_
 
     # -- coalescing ----------------------------------------------------
     def _answer(self, batch: List[_Request]) -> None:
