@@ -385,7 +385,16 @@ class ReconfigTorus:
         path (an accelerator engine answers both sub-block freeness and
         free counts itself — no host integral image is ever built).
         When only a few cubes changed since the last refresh (tracked
-        by place/release), just those rows are recomputed."""
+        by place/release), just those rows are recomputed.
+
+        With a client, a refresh that will need fit masks asks for them
+        first and for the free counts second, on the same occupancy:
+        the rows of the cached shapes on a partial refresh, every shape
+        seen so far on a full one (a compiled client only; a host-free
+        one stays lazy, see :meth:`_shape_fit_mask`). A device engine
+        answers both from its one multibox call, and a broker from its
+        one flush. The answers are pure functions of the occupancy, so
+        the order changes no result."""
         if self._cache_epoch == self._epoch:
             return
         n3 = self.cube_n ** 3
@@ -408,22 +417,29 @@ class ReconfigTorus:
                             m[d, :w.shape[1], :w.shape[2], :w.shape[3]] = \
                                 w == 0
                 else:
-                    self._free_cnt[d] = client.free_counts(self.occ[d])
+                    occ_d = self.occ[d]
                     if self._shape_masks:
                         shapes = sorted(self._shape_masks)
-                        out = client.multibox(self.occ[d], shapes)
+                        out = client.multibox(occ_d, shapes)
                         for k, s in enumerate(shapes):
                             self._shape_masks[s][d] = out[:, k] != 0
+                    self._free_cnt[d] = client.free_counts(occ_d)
                 self._cube_empty[d] = self._free_cnt[d] == n3
         else:
+            self._shape_masks = {}
             if client is None:
                 self._ii = fitmask.batched_integral_image(self.occ)
                 self._free_cnt = n3 - self._ii[:, -1, -1, -1]
             else:
                 self._ii = None
+                if self._seen_shapes and not getattr(client, "host_free",
+                                                     False):
+                    shapes = sorted(self._seen_shapes)
+                    out = client.multibox(self.occ, shapes)
+                    for k, s in enumerate(shapes):
+                        self._shape_masks[s] = out[:, k] != 0
                 self._free_cnt = client.free_counts(self.occ)
             self._cube_empty = self._free_cnt == n3
-            self._shape_masks = {}
         # Best-fit ordering: least leftover first, non-empty cubes break
         # ties (the piece size shifts every key equally, so one key
         # serves all piece sizes); np.argmin's first-minimum rule becomes

@@ -16,6 +16,9 @@ batching is the tiling axis.
   the same (B, K, X, Y, Z) shape. Each box's differences along Y and Z
   are dynamic rotations of a lane-aligned ``(Yp, Zp)`` slab of the
   integral image, and origins where the box overhangs are masked.
+* The same program writes each grid's occupied-cell count beside its
+  planes, so one dispatch answers an occupancy refresh (planes and
+  free counts); :func:`occupancy_counts` is the counts alone.
 
 Every entry point takes ``interpret`` explicitly: the one place that
 chooses it is ``repro.kernels.fitmask.ops.pallas_interpret`` (compiled
@@ -81,11 +84,22 @@ def _integral_image(occ_ref, ii_ref) -> None:
         ii_ref[i + 1] = acc
 
 
-def _fitmask_multibox_kernel(boxes_ref, occ_ref, out_ref, ii_ref):
+def _write_count(occ_ref, count_ref) -> None:
+    """Write the occupied-cell count of the grid in ``occ_ref[0]``
+    across one lane-dense (1, 128) row of ``count_ref``."""
+    plane = jnp.sum(occ_ref[0], axis=0)                    # (Y, Z)
+    total = jnp.sum(plane, axis=(0, 1), keepdims=True)     # (1, 1)
+    count_ref[0] = jnp.broadcast_to(total, (1, _LANES))
+
+
+def _fitmask_multibox_kernel(boxes_ref, occ_ref, out_ref, count_ref,
+                             ii_ref):
     """One grid per program: build its integral image once in VMEM,
-    then answer the K boxes of the SMEM table in a loop."""
+    then answer the K boxes of the SMEM table in a loop; the grid's
+    occupied-cell count is written beside the planes."""
     _, x, y, z = occ_ref.shape
     yp, zp = ii_ref.shape[1:]
+    _write_count(occ_ref, count_ref)
     _integral_image(occ_ref, ii_ref)
     row = jax.lax.broadcasted_iota(jnp.int32, (yp, zp), 0)
     col = jax.lax.broadcasted_iota(jnp.int32, (yp, zp), 1)
@@ -110,26 +124,32 @@ def _fitmask_multibox_kernel(boxes_ref, occ_ref, out_ref, ii_ref):
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def _fitmask_multibox(occ: jnp.ndarray, table: jnp.ndarray, *,
-                      interpret: bool) -> jnp.ndarray:
+                      interpret: bool) -> Tuple[jnp.ndarray, jnp.ndarray]:
     bsz, x, y, z = occ.shape
     k = table.shape[0]
+    occ = occ.astype(jnp.int32)
     if k == 0:
-        return jnp.zeros((bsz, 0, x, y, z), jnp.int32)
+        return (jnp.zeros((bsz, 0, x, y, z), jnp.int32),
+                jnp.sum(occ, axis=(1, 2, 3)))
     yp, zp = _round_up(y + 1, _SUBLANES), _round_up(z + 1, _LANES)
-    return pl.pallas_call(
+    planes, counts = pl.pallas_call(
         _fitmask_multibox_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(bsz,),
             in_specs=[pl.BlockSpec((1, x, y, z),
                                    lambda i, boxes: (i, 0, 0, 0))],
-            out_specs=pl.BlockSpec((1, k, x, y, z),
-                                   lambda i, boxes: (i, 0, 0, 0, 0)),
+            out_specs=[pl.BlockSpec((1, k, x, y, z),
+                                    lambda i, boxes: (i, 0, 0, 0, 0)),
+                       pl.BlockSpec((1, 1, _LANES),
+                                    lambda i, boxes: (i, 0, 0))],
             scratch_shapes=[pltpu.VMEM((x + 1, yp, zp), jnp.int32)]),
-        out_shape=jax.ShapeDtypeStruct((bsz, k, x, y, z), jnp.int32),
+        out_shape=[jax.ShapeDtypeStruct((bsz, k, x, y, z), jnp.int32),
+                   jax.ShapeDtypeStruct((bsz, 1, _LANES), jnp.int32)],
         interpret=interpret,
         name="fitmask_multibox",
-    )(table.astype(jnp.int32), occ.astype(jnp.int32))
+    )(table.astype(jnp.int32), occ)
+    return planes, counts[:, 0, 0]
 
 
 def _box_table(boxes: Sequence[Box]) -> np.ndarray:
@@ -148,13 +168,23 @@ def fitmask_multibox(occ: jnp.ndarray, boxes: Sequence[Box], *,
     all-zero planes, so callers never special-case K. The program is
     compiled per (B, K, X, Y, Z) shape, never per box set.
     """
+    return fitmask_multibox_counts(occ, boxes, interpret=interpret)[0]
+
+
+def fitmask_multibox_counts(occ: jnp.ndarray, boxes: Sequence[Box], *,
+                            interpret: bool
+                            ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """:func:`fitmask_multibox`'s planes and, from the same program,
+    each grid's occupied-cell count: ``((B, K, X, Y, Z), (B,))`` int32.
+    One dispatch answers an occupancy refresh; the counts are what
+    :func:`occupancy_counts` returns for the same grids."""
     return _fitmask_multibox(occ, _box_table(boxes), interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def _fitmask_batched(occ: jnp.ndarray, table: jnp.ndarray, *,
                      interpret: bool) -> jnp.ndarray:
-    return _fitmask_multibox(occ, table, interpret=interpret)[:, 0]
+    return _fitmask_multibox(occ, table, interpret=interpret)[0][:, 0]
 
 
 def fitmask_batched(occ: jnp.ndarray, box: Box, *,
@@ -165,27 +195,23 @@ def fitmask_batched(occ: jnp.ndarray, box: Box, *,
     return _fitmask_batched(occ, _box_table([box]), interpret=interpret)
 
 
-def _occupancy_counts_kernel(occ_ref, out_ref):
-    plane = jnp.sum(occ_ref[0], axis=0)                    # (Y, Z)
-    total = jnp.sum(plane, axis=(0, 1), keepdims=True)     # (1, 1)
-    out_ref[0] = jnp.broadcast_to(total, (1, _LANES))
-
-
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def occupancy_counts(occ: jnp.ndarray, *, interpret: bool) -> jnp.ndarray:
     """Occupied-cell count per grid: (B, X, Y, Z) bool/int -> (B,) int32.
 
     The engine registry's ``free_counts`` query runs on this (free =
-    X*Y*Z - occupied): the reconfigurable-torus allocator needs per-cube
-    free counts for its best-fit ordering every occupancy epoch, and
-    answering them device-side is what lets accelerator engines drop the
-    host integral-image pass entirely. One program per grid, whole grid
+    X*Y*Z - occupied) when no multibox call has just answered the same
+    occupancy (:func:`fitmask_multibox_counts` returns the same counts):
+    the reconfigurable-torus allocator needs per-cube free counts for
+    its best-fit ordering every occupancy epoch, and answering them
+    device-side is what lets accelerator engines drop the host
+    integral-image pass entirely. One program per grid, whole grid
     in VMEM (same batching axis as the fitmask kernel); each program
     writes its count across one lane-dense (1, 128) row, of which lane
     0 is returned."""
     bsz, x, y, z = occ.shape
     out = pl.pallas_call(
-        _occupancy_counts_kernel,
+        _write_count,
         grid=(bsz,),
         in_specs=[pl.BlockSpec((1, x, y, z), lambda i: (i, 0, 0, 0))],
         out_specs=pl.BlockSpec((1, 1, _LANES), lambda i: (i, 0, 0)),
