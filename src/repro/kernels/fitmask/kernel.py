@@ -8,6 +8,17 @@ cubes/candidate grids on the Pallas grid axis. Cluster grids are tiny
 (<= 64^3 int32 = 1 MiB), so a whole grid fits VMEM comfortably;
 batching is the tiling axis.
 
+* The answer planes are not tiny: each (X, Y, Z) int32 plane has its
+  Z on the 128 lanes, so a 16^3 plane takes 128 KiB of VMEM, eight
+  times its size. K is therefore the second grid axis, cut into tiles
+  of at most 2 MiB of planes (:func:`k_tile`): a 4^3 grid keeps every
+  K up to 128 in one tile, a 16^3 grid runs 16 boxes per tile. The
+  integral image is built into scratch at a grid's first tile and read
+  by the others, so the K axis runs in order ("arbitrary"). The planes
+  keep the (X, Y, Z) layout: a lane-dense (Y * Z on lanes) block would
+  cut the padding at 16^3, but no compile or chip reading has shown it
+  to pay, so it is not done.
+
 * The prefix sums are matmuls against strictly triangular 0/1 matrices
   (Mosaic has no cumsum lowering), which also write the integral
   image's zero border; the leading axis is a running sum of slabs.
@@ -27,6 +38,7 @@ on a TPU, interpreted on the CPU backend, refused elsewhere).
 from __future__ import annotations
 
 import functools
+import math
 from typing import Sequence, Tuple
 
 import jax
@@ -43,6 +55,9 @@ Box = Tuple[int, int, int]
 # the smallest per-grid output block the TPU compiler accepts.
 _SUBLANES = 8
 _LANES = 128
+# VMEM for one output block of the multibox kernel: K is cut into tiles
+# of at most this many bytes of padded answer planes (see k_tile).
+_K_TILE_BYTES = 2 << 20
 
 
 def _round_up(n: int, m: int) -> int:
@@ -92,20 +107,47 @@ def _write_count(occ_ref, count_ref) -> None:
     count_ref[0] = jnp.broadcast_to(total, (1, _LANES))
 
 
+def _plane_vmem_bytes(x: int, y: int, z: int) -> int:
+    """VMEM bytes of one (X, Y, Z) int32 answer plane: each (Y, Z) slab
+    is padded to whole (8, 128) tiles."""
+    return 4 * x * _round_up(y, _SUBLANES) * _round_up(z, _LANES)
+
+
+def k_tile(k: int, grid: Sequence[int]) -> int:
+    """Boxes per program of the multibox kernel for K boxes on (X, Y, Z)
+    grids: the largest power of two that divides K and keeps one output
+    block within ``_K_TILE_BYTES`` of VMEM. A 4^3 plane takes 16 KiB,
+    so every K <= 128 there is one tile; a 16^3 plane takes 128 KiB
+    (Z = 16 on 128 lanes), so K is cut into tiles of 16."""
+    cap = max(1, _K_TILE_BYTES // _plane_vmem_bytes(*grid))
+    cap = 1 << (cap.bit_length() - 1)
+    return math.gcd(max(k, 1), cap)
+
+
 def _fitmask_multibox_kernel(boxes_ref, occ_ref, out_ref, count_ref,
                              ii_ref):
-    """One grid per program: build its integral image once in VMEM,
-    then answer the K boxes of the SMEM table in a loop; the grid's
-    occupied-cell count is written beside the planes."""
+    """Program (grid, K tile): the grid's integral image is built into
+    VMEM scratch once, at its first K tile, with the grid's
+    occupied-cell count; every tile then answers its own boxes of the
+    SMEM table from that image, in a loop."""
     _, x, y, z = occ_ref.shape
+    kt = out_ref.shape[1]
     yp, zp = ii_ref.shape[1:]
-    _write_count(occ_ref, count_ref)
-    _integral_image(occ_ref, ii_ref)
+    tile = pl.program_id(1)
+
+    @pl.when(tile == 0)
+    def _():
+        _write_count(occ_ref, count_ref)
+        _integral_image(occ_ref, ii_ref)
+
     row = jax.lax.broadcasted_iota(jnp.int32, (yp, zp), 0)
     col = jax.lax.broadcasted_iota(jnp.int32, (yp, zp), 1)
+    base = tile * kt
 
     def one_box(k, carry):
-        a, b, c = boxes_ref[k, 0], boxes_ref[k, 1], boxes_ref[k, 2]
+        a = boxes_ref[base + k, 0]
+        b = boxes_ref[base + k, 1]
+        c = boxes_ref[base + k, 2]
         inside = (row + b <= y) & (col + c <= z)
         # Rotating by (len - d) brings index j + d to j; the wrapped
         # tail lands only where ``inside`` is false.
@@ -119,11 +161,11 @@ def _fitmask_multibox_kernel(boxes_ref, occ_ref, out_ref, count_ref,
             out_ref[0, k, i] = fits[:y, :z].astype(jnp.int32)
         return carry
 
-    jax.lax.fori_loop(0, out_ref.shape[1], one_box, 0)
+    jax.lax.fori_loop(0, kt, one_box, 0)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def _fitmask_multibox(occ: jnp.ndarray, table: jnp.ndarray, *,
+@functools.partial(jax.jit, static_argnames=("tile", "interpret"))
+def _fitmask_multibox(occ: jnp.ndarray, table: jnp.ndarray, *, tile: int,
                       interpret: bool) -> Tuple[jnp.ndarray, jnp.ndarray]:
     bsz, x, y, z = occ.shape
     k = table.shape[0]
@@ -136,16 +178,20 @@ def _fitmask_multibox(occ: jnp.ndarray, table: jnp.ndarray, *,
         _fitmask_multibox_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(bsz,),
+            grid=(bsz, k // tile),
             in_specs=[pl.BlockSpec((1, x, y, z),
-                                   lambda i, boxes: (i, 0, 0, 0))],
-            out_specs=[pl.BlockSpec((1, k, x, y, z),
-                                    lambda i, boxes: (i, 0, 0, 0, 0)),
+                                   lambda i, j, boxes: (i, 0, 0, 0))],
+            out_specs=[pl.BlockSpec((1, tile, x, y, z),
+                                    lambda i, j, boxes: (i, j, 0, 0, 0)),
                        pl.BlockSpec((1, 1, _LANES),
-                                    lambda i, boxes: (i, 0, 0))],
+                                    lambda i, j, boxes: (i, 0, 0))],
             scratch_shapes=[pltpu.VMEM((x + 1, yp, zp), jnp.int32)]),
         out_shape=[jax.ShapeDtypeStruct((bsz, k, x, y, z), jnp.int32),
                    jax.ShapeDtypeStruct((bsz, 1, _LANES), jnp.int32)],
+        # The K tiles of a grid run in order on one core: the first
+        # builds the integral image the others read.
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
         name="fitmask_multibox",
     )(table.astype(jnp.int32), occ)
@@ -178,13 +224,17 @@ def fitmask_multibox_counts(occ: jnp.ndarray, boxes: Sequence[Box], *,
     each grid's occupied-cell count: ``((B, K, X, Y, Z), (B,))`` int32.
     One dispatch answers an occupancy refresh; the counts are what
     :func:`occupancy_counts` returns for the same grids."""
-    return _fitmask_multibox(occ, _box_table(boxes), interpret=interpret)
+    table = _box_table(boxes)
+    return _fitmask_multibox(occ, table,
+                             tile=k_tile(len(table), occ.shape[1:]),
+                             interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def _fitmask_batched(occ: jnp.ndarray, table: jnp.ndarray, *,
                      interpret: bool) -> jnp.ndarray:
-    return _fitmask_multibox(occ, table, interpret=interpret)[0][:, 0]
+    return _fitmask_multibox(occ, table, tile=1,
+                             interpret=interpret)[0][:, 0]
 
 
 def fitmask_batched(occ: jnp.ndarray, box: Box, *,

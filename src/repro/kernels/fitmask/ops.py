@@ -140,11 +140,17 @@ class FitmaskEngine:
     ``host_free``
         True when ``free_counts`` is a cheap host reduction that is
         faster answered inline than coalesced through a broker round.
+    ``compiles_boxes``
+        True when the box set is compiled into the program, so that
+        only a monotone per-bucket box table keeps the broker's flushes
+        to a few programs. False for an engine that reads its boxes as
+        data: the broker sends each flush its own union.
     """
 
     name = "base"
     pads_shapes = False
     host_free = False
+    compiles_boxes = False
 
     def __init__(self) -> None:
         # Input shapes of the device programs called so far.
@@ -221,6 +227,7 @@ class JaxEngine(FitmaskEngine):
 
     name = "jax"
     pads_shapes = True
+    compiles_boxes = True
 
     @staticmethod
     @functools.cache
@@ -405,7 +412,12 @@ class PallasEngine(FitmaskEngine):
     copy — opening an ``engine.reuse`` span. Any ``free_counts`` uses
     the entry up; another occupancy dispatches ``occupancy_counts``.
     So a refresh that asks for planes, then counts, costs one device
-    call, and so does the default ``multibox_bucketed``."""
+    call, and so does the default ``multibox_bucketed``.
+
+    The boxes are data, so the fleet broker sends each flush its own
+    box union, padded to a power of two, rather than a bucket's whole
+    box table. On large grids the kernel cuts K into tiles
+    (``kernel.k_tile``); ``engine.call`` carries ``k_tiles``."""
 
     name = "pallas"
     pads_shapes = True
@@ -424,11 +436,13 @@ class PallasEngine(FitmaskEngine):
             return np.zeros((bsz, 0) + occ.shape[1:], np.int32)
         table = boxes + boxes[-1:] * (_pow2(k) - k)
         grids = _pad_grids(occ)
+        tiles = len(table) // _kernel.k_tile(len(table), occ.shape[1:])
         planes, used = _on_device(
             self, "multibox", (grids.shape, len(table)),
             lambda: _kernel.fitmask_multibox_counts(
                 grids, table, interpret=pallas_interpret()),
-            b=bsz, b_pad=grids.shape[0], k=k, k_pad=len(table))
+            b=bsz, b_pad=grids.shape[0], k=k, k_pad=len(table),
+            k_tiles=tiles)
         n3 = int(np.prod(occ.shape[1:]))
         self._answered.last = (occ.copy(), n3 - used[:bsz])
         return planes[:bsz, :k]

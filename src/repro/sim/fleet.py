@@ -33,7 +33,8 @@ through a shared :class:`QueryBroker`:
     its own box order.
   * Compiled engines see a *small, stable* set of program shapes: per
     bucket, B is padded to the fleet hint or the next power of two and
-    the K axis is served from a monotone per-bucket **box table** —
+    the K axis is served from a monotone per-bucket **box table**
+    (engines that compile their boxes in, see below) —
     power-of-two padded while the table is still collecting boxes,
     exact-length once it stops growing — so XLA settles on one fused
     program per bucket instead of one per distinct flush union. The
@@ -41,18 +42,29 @@ through a shared :class:`QueryBroker`:
     policy (``FitmaskEngine.pads_shapes``) plus bucket-local state;
     the host numpy engine is never padded (extra grids are pure waste
     there).
+  * The table is for engines that compile their boxes into the
+    program (``FitmaskEngine.compiles_boxes``). An engine that reads
+    its boxes as data (``pallas``) runs any box set of one padded K on
+    one program, so each flush is sent its own **union**, padded to a
+    power of two and indexed per flush: K stays within the few shapes
+    set-up can warm, and each flush computes and copies back the
+    planes it asked for rather than the whole history's — a 16^3
+    static torus meets hundreds of distinct boxes in a sweep, where a
+    flush needs a few dozen.
 
 Why schedules stay byte-identical to the single-sim path: every
 ``multibox``/``free_counts`` answer is a pure per-grid-per-box
 function of the submitted occupancy — batching concatenates inputs
-and slices outputs, it never mixes grids — so a simulator cannot
-observe whether its query was answered solo, in a quorum round of
-three, or in a timeout round of one: *which* round answers a query
-changes with interleaving, but the answer bytes cannot (parity-tested
-across randomized interleavings, quorum fractions and timeout firings
-in ``tests/test_fleet.py``; the per-sim epoch caches in the torus
-models are untouched and keep deduplicating queries before they ever
-reach the broker).
+and slices outputs, it never mixes grids, and which other boxes share
+the K axis (a table, a union, their padding duplicates) never changes
+a box's plane — so a simulator cannot observe whether its query was
+answered solo, in a quorum round of three, or in a timeout round of
+one, from its bucket's table or from the round's union: *which* round
+answers a query changes with interleaving, but the answer bytes
+cannot (parity-tested across randomized interleavings, quorum
+fractions, timeout firings and both K rules in ``tests/test_fleet.py``;
+the per-sim epoch caches in the torus models are untouched and keep
+deduplicating queries before they ever reach the broker).
 
 The broker implements the ``repro.core.maskquery`` client contract,
 so installing it is one call per policy (:func:`install_mask_client`).
@@ -130,6 +142,11 @@ _WATCHDOG_TICK = 0.05
 # Post-failover parity canary: how many multibox flushes on the
 # adopted engine are cross-checked against the host numpy oracle.
 _CANARY_FLUSHES = 3
+
+
+def _pow2(n: int) -> int:
+    """Box slots for ``n`` boxes: the next power of two."""
+    return max(1, 1 << (n - 1).bit_length())
 
 
 @dataclass
@@ -263,9 +280,10 @@ class QueryBroker(MaskQueryClient):
     ``pad_b="auto"`` defers to the engine's ``pads_shapes`` policy:
     compiled engines get per-bucket stable shapes — B padded up to the
     fleet hint / bucket high-water power of two, K served from the
-    bucket's padded box table — while the host engine always sees
-    exact shapes. Padding rows and spare K slots are sliced off before
-    answers are handed back, so results are unchanged.
+    bucket's padded box table or the flush's padded union — while the
+    host engine always sees exact shapes. Padding rows and spare K
+    slots are sliced off before answers are handed back, so results
+    are unchanged.
     """
 
     def __init__(self, engine=None, quorum: Optional[float] = 1.0,
@@ -479,8 +497,9 @@ class QueryBroker(MaskQueryClient):
         their results landed)."""
         while True:
             with obs.span("broker.flush") as sp:
+                plans: List[Tuple[str, int, int]] = []
                 try:
-                    self._answer(batch)
+                    plans = self._answer(batch)
                 except BaseException as e:  # noqa: BLE001 — must wake waiters
                     for r in batch:
                         if r.result is None and r.error is None:
@@ -488,6 +507,10 @@ class QueryBroker(MaskQueryClient):
                 if sp.recording:
                     sp.tag(trigger=trigger, requests=len(batch),
                            grids=sum(r.occ.shape[0] for r in batch))
+                    if plans:
+                        sp.tag(rule="+".join(sorted({p[0] for p in plans})),
+                               k_served=sum(p[1] for p in plans),
+                               k_needed=sum(p[2] for p in plans))
                     for r in batch:
                         r.trigger = trigger
             for r in batch:
@@ -500,7 +523,10 @@ class QueryBroker(MaskQueryClient):
             batch, trigger = round_
 
     # -- coalescing ----------------------------------------------------
-    def _answer(self, batch: List[_Request]) -> None:
+    def _answer(self, batch: List[_Request]) -> List[Tuple[str, int, int]]:
+        """Answer a round; returns the K plan of each multibox bucket
+        (rule, box slots served, box slots needed)."""
+        plans = []
         for kind in ("multibox", "free_counts"):
             reqs = [r for r in batch if r.kind == kind]
             # Bucket by grid cell shape: only same-shape grids can
@@ -510,9 +536,10 @@ class QueryBroker(MaskQueryClient):
                 by_cell.setdefault(r.occ.shape[1:], []).append(r)
             for cell, group in by_cell.items():
                 if kind == "multibox":
-                    self._answer_multibox(cell, group)
+                    plans.append(self._answer_multibox(cell, group))
                 else:
                     self._answer_free_counts(cell, group)
+        return plans
 
     # Per-bucket padding plan: the decision is bucket-local, not
     # engine-global — each bucket tracks its own stable B target (the
@@ -554,39 +581,50 @@ class QueryBroker(MaskQueryClient):
             return occs[0], b, pad
         return np.concatenate(occs, axis=0), b, pad
 
-    def _boxes_for(self, cell: Tuple[int, ...],
-                   needed: Tuple[Box, ...]) -> Tuple[Tuple[Box, ...],
-                                                     Dict[Box, int]]:
-        """K plan for one flush. Host engines get exactly the needed
-        union. Compiled engines are served from the bucket's monotone
-        box table: power-of-two padded while the table is growing
-        (spare slots filled with a *duplicate* of an existing box,
-        which the fused program's trace-time dedup makes nearly free),
-        then exact-length once the table has been stable for
-        ``_STABLE_FLUSHES`` flushes — the steady state is one
-        compiled program at exact K, reused for every flush."""
+    def _boxes_for(self, cell: Tuple[int, ...], needed: Tuple[Box, ...]
+                   ) -> Tuple[Tuple[Box, ...], Dict[Box, int], str]:
+        """K plan for one flush: the boxes to send, each needed box's
+        index among them, and the rule that chose them (``table`` or
+        ``union``). Host engines get exactly the needed union. Engines
+        that read boxes as data get the union padded to a power of two
+        with a duplicate, indexed per flush. Engines that compile their
+        boxes in (``compiles_boxes``) are served from the bucket's
+        monotone box table: power-of-two padded while the table is
+        growing (spare slots filled with a *duplicate* of an existing
+        box, which the fused program's trace-time dedup makes nearly
+        free), then exact-length once the table has been stable for
+        ``_STABLE_FLUSHES`` flushes — the steady state is one compiled
+        program at exact K, reused for every flush."""
+        kidx = {b: k for k, b in enumerate(needed)}
         if not self.pad_b:
-            return needed, {b: k for k, b in enumerate(needed)}
+            return needed, kidx, "union"
         with self._lock:
-            bucket = self._buckets.setdefault(cell, _Bucket())
-            before = len(bucket.table)
-            for b in needed:
-                if b not in bucket.index:
-                    bucket.index[b] = len(bucket.table)
-                    bucket.table.append(b)
-            if len(bucket.table) != before:
-                bucket.since_growth = 0
+            if not getattr(self.engine, "compiles_boxes", False):
+                filler = needed[0] if needed else _PAD_BOX
+                boxes = needed + (filler,) * (_pow2(len(needed))
+                                              - len(needed))
+                rule = "union"
             else:
-                bucket.since_growth += 1
-            table = tuple(bucket.table)
-            if bucket.since_growth < _STABLE_FLUSHES:
-                cap = max(1, 1 << (len(table) - 1).bit_length())
-                filler = table[0] if table else _PAD_BOX
-                table = table + (filler,) * (cap - len(table))
-            kidx = dict(bucket.index)
-            self.stats.k_slots += len(table)
+                bucket = self._buckets.setdefault(cell, _Bucket())
+                before = len(bucket.table)
+                for b in needed:
+                    if b not in bucket.index:
+                        bucket.index[b] = len(bucket.table)
+                        bucket.table.append(b)
+                if len(bucket.table) != before:
+                    bucket.since_growth = 0
+                else:
+                    bucket.since_growth += 1
+                boxes = tuple(bucket.table)
+                if bucket.since_growth < _STABLE_FLUSHES:
+                    filler = boxes[0] if boxes else _PAD_BOX
+                    boxes = boxes + (filler,) * (_pow2(len(boxes))
+                                                 - len(boxes))
+                kidx = dict(bucket.index)
+                rule = "table"
+            self.stats.k_slots += len(boxes)
             self.stats.k_needed += len(needed)
-        return table, kidx
+        return boxes, kidx, rule
 
     # -- engine dispatch: retry, failover, canary ---------------------
     def inject_engine_faults(self, n: int) -> None:
@@ -711,9 +749,11 @@ class QueryBroker(MaskQueryClient):
                 self.stats.canary_mismatches += 1
 
     def _answer_multibox(self, cell: Tuple[int, ...],
-                         group: List[_Request]) -> None:
+                         group: List[_Request]) -> Tuple[str, int, int]:
+        """Answer one bucket's multibox requests in one engine call;
+        returns the K rule with the box slots served and needed."""
         union = tuple(sorted({b for r in group for b in r.boxes}))
-        boxes, kidx = self._boxes_for(cell, union)
+        boxes, kidx, rule = self._boxes_for(cell, union)
         occ, real_b, pad = self._stack(cell, group)
         planes, free = self._engine_call("multibox", occ, boxes)
         self._maybe_canary(occ, boxes, planes)
@@ -742,6 +782,7 @@ class QueryBroker(MaskQueryClient):
                     self._fc_cache.move_to_end(key)
                 while len(self._fc_cache) > _FC_CACHE_CAP:
                     self._fc_cache.popitem(last=False)
+        return rule, len(boxes), len(union)
 
     def _answer_free_counts(self, cell: Tuple[int, ...],
                             group: List[_Request]) -> None:

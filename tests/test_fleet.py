@@ -463,6 +463,147 @@ def test_interleaving_parity_on_compiled_engine_with_padding():
                     np.asarray(oracle.free_counts(occ)))
 
 
+# ------------------------------------------------ the K rules
+class _PaddedEngine(ops.NumpyEngine):
+    """The numpy engine, declared compiled (padded shapes, no host free
+    counts), reading its boxes as data or compiling them in; records
+    each call's K."""
+
+    name = "padded"
+    pads_shapes = True
+    host_free = False
+
+    def __init__(self, compiles_boxes):
+        super().__init__()
+        self.compiles_boxes = compiles_boxes
+        self.ks = []
+
+    def multibox_bucketed(self, occ, boxes):
+        self.ks.append(len(boxes))
+        return super().multibox_bucketed(occ, boxes)
+
+
+def test_union_rule_for_an_engine_that_reads_boxes_as_data():
+    """Each flush is served its own union padded to a power of two,
+    whatever boxes earlier flushes asked for, and every answer is the
+    numpy engine's."""
+    eng = _PaddedEngine(compiles_boxes=False)
+    broker = QueryBroker(eng)
+    oracle = ops.get_engine("numpy")
+    rng = np.random.default_rng(21)
+    queries = [((1, 1, 1), (2, 1, 1), (1, 2, 1)),   # union of 3 -> K 4
+               ((2, 1, 1),),                        # union of 1
+               ((3, 1, 1), (1, 1, 3)),              # union of 2
+               ((1, 1, 1), (2, 2, 2), (3, 3, 1),
+                (1, 3, 1), (4, 1, 1)),              # union of 5 -> K 8
+               ((1, 1, 1),)]                        # union of 1
+    for boxes in queries:
+        occ = _occ(rng, 1, (5, 5, 5))
+        got = broker.multibox(occ, boxes)
+        np.testing.assert_array_equal(np.asarray(got) != 0,
+                                      oracle.multibox(occ, boxes) != 0)
+    assert eng.ks == [4, 1, 2, 8, 1]
+    assert broker.stats.k_slots == sum(eng.ks)
+    assert broker.stats.k_needed == 3 + 1 + 2 + 5 + 1
+
+
+def test_engine_that_compiles_boxes_keeps_the_table_rule():
+    """An engine that compiles its boxes into the program keeps growing
+    its bucket's table."""
+    eng = _PaddedEngine(compiles_boxes=True)
+    broker = QueryBroker(eng)
+    occ = np.zeros((1, 5, 5, 5), bool)
+    for box in [(1, 1, 1), (2, 1, 1), (3, 1, 1), (4, 1, 1), (5, 1, 1)]:
+        broker.multibox(occ, (box,))
+    assert eng.ks == [1, 2, 4, 4, 8]
+
+
+def test_engine_k_rules():
+    """``pallas`` reads its boxes as data; ``jax`` compiles them in."""
+    assert not ops.PallasEngine.compiles_boxes
+    assert ops.JaxEngine.compiles_boxes
+
+
+@pytest.mark.parametrize("compiles_boxes,want", [
+    (False, [("union", 2, 2), ("union", 4, 3)]),
+    (True, [("table", 2, 2), ("table", 4, 3)]),
+])
+def test_flush_span_tags_the_k_rule(tmp_path, compiles_boxes, want):
+    """While recording, ``broker.flush`` says which rule served it and
+    how many box slots it served and needed."""
+    import jax
+    from repro import obs
+    eng = _PaddedEngine(compiles_boxes=compiles_boxes)
+    broker = QueryBroker(eng)
+    occ = np.zeros((1, 4, 4, 4), bool)
+    t0 = time.perf_counter()
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    with jax.profiler.trace(str(tmp_path / "trace"), profiler_options=opts):
+        broker.multibox(occ, ((1, 1, 1), (2, 1, 1)))
+        broker.multibox(occ, ((1, 1, 1), (3, 1, 1), (1, 1, 2)))
+    flushes = [r.tags for r in obs.records()
+               if r.t0 >= t0 and r.name == "broker.flush"]
+    assert [(f["rule"], f["k_served"], f["k_needed"])
+            for f in flushes] == want
+
+
+@settings(max_examples=12, deadline=None)
+@given(st.integers(0, 2**31 - 1),
+       st.booleans(),
+       st.sampled_from([0.5, 1.0]),
+       st.sampled_from([-1, 0, 1]))   # -1: no deadline; ms otherwise
+def test_union_rule_byte_identical_under_random_interleaving(
+        seed, compiles_boxes, quorum, timeout_ms):
+    """Under either K rule, every answer across randomized stepper
+    interleavings is the numpy engine's on the same inputs, and every
+    K the union rule sends is a power of two."""
+    timeout = None if timeout_ms < 0 else timeout_ms / 1000.0
+    rng = np.random.default_rng(seed)
+    cell = tuple(int(v) for v in rng.integers(3, 7, size=3))
+    n = int(rng.integers(2, 5))
+    plans = _random_query_plan(rng, cell, n)
+    eng = _PaddedEngine(compiles_boxes=compiles_boxes)
+    oracle = ops.get_engine("numpy")
+    broker = QueryBroker(eng, quorum=quorum, timeout=timeout)
+    outs = [[] for _ in range(n)]
+    errs = []
+
+    def stepper(i):
+        r = np.random.default_rng(seed ^ (i + 1))
+        try:
+            for kind, occ, boxes in plans[i]:
+                time.sleep(float(r.random()) * 0.002)  # interleave
+                if kind == "multibox":
+                    outs[i].append(broker.multibox(occ, boxes))
+                else:
+                    outs[i].append(broker.free_counts(occ))
+        except BaseException as e:  # noqa: BLE001 — surfaced below
+            errs.append(e)
+        finally:
+            broker.deactivate()
+
+    for _ in range(n):
+        broker.register()
+    threads = [threading.Thread(target=stepper, args=(i,), daemon=True)
+               for i in range(n)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout=30)
+    assert not errs and not any(t.is_alive() for t in threads)
+    for i, steps in enumerate(plans):
+        for (kind, occ, boxes), got in zip(steps, outs[i]):
+            if kind == "multibox":
+                np.testing.assert_array_equal(
+                    np.asarray(got) != 0, oracle.multibox(occ, boxes) != 0)
+            else:
+                np.testing.assert_array_equal(
+                    np.asarray(got), oracle.free_counts(occ))
+    if not compiles_boxes:
+        assert all(k & (k - 1) == 0 for k in eng.ks)
+
+
 # ------------------------------------------------- chunking / sizing
 def test_task_grid_bucket_defaults_mirror_make_policy():
     tasks = _tasks(runs=1)

@@ -190,6 +190,60 @@ def test_fitmask_multibox_empty_box_list():
     assert out.shape == (2, 0, 4, 4, 4)
 
 
+def _tiled_case(seed, bsz, n, k):
+    """Random (bsz, n, n, n) occupancy and K boxes, some overhanging."""
+    rng = np.random.default_rng(seed)
+    occ = rng.uniform(size=(bsz, n, n, n)) < 0.3
+    boxes = tuple(tuple(int(v) for v in rng.integers(1, n + 2, size=3))
+                  for _ in range(k))
+    return occ, boxes
+
+
+@pytest.mark.parametrize("n,k,tile", [(16, 8, 2), (16, 6, 3), (4, 16, 4),
+                                      (4, 8, 8)])
+def test_fitmask_multibox_k_tiles_match_engines(n, k, tile):
+    """K cut into tiles (the integral image built at the first tile,
+    the others reading it from scratch) answers every box as the numpy
+    and ``ref`` engines do, counts included, on 16^3 and on 4^3."""
+    occ, boxes = _tiled_case(n * 100 + k, 2, n, k)
+    table = fit_kernel._box_table(boxes)
+    planes, counts = fit_kernel._fitmask_multibox(
+        jnp.array(occ), table, tile=tile, interpret=True)
+    planes = np.asarray(planes)
+    assert planes.shape == (2, k, n, n, n)
+    np.testing.assert_array_equal(
+        planes, fit_ops.get_engine("numpy").multibox(occ, boxes))
+    np.testing.assert_array_equal(
+        planes, np.asarray(fit_ops.get_engine("ref").multibox(occ, boxes)))
+    np.testing.assert_array_equal(np.asarray(counts), occ.sum(axis=(1, 2, 3)))
+
+
+def test_k_tile_keeps_small_grids_whole_and_tiles_16_cubed():
+    """The tile comes from the grid's padded plane size: every K up to
+    64 on a 4^3 grid is one tile (with the same results as before),
+    a 16^3 grid runs 16 boxes per tile, and a tile always divides K."""
+    for k in (1, 2, 8, 64):
+        assert fit_kernel.k_tile(k, (4, 4, 4)) == k
+    assert fit_kernel.k_tile(128, (16, 16, 16)) == 16
+    assert fit_kernel.k_tile(8, (16, 16, 16)) == 8
+    for k in range(1, 130):
+        for grid in ((4, 4, 4), (8, 8, 8), (16, 16, 16), (64, 64, 64)):
+            tile = fit_kernel.k_tile(k, grid)
+            assert tile >= 1 and k % tile == 0
+
+
+def test_fitmask_multibox_16_cubed_public_entry_tiles_k():
+    """The public entry picks the tile itself: 32 boxes on a 16^3 grid
+    run as two tiles and match the numpy engine."""
+    occ, boxes = _tiled_case(5, 1, 16, 32)
+    assert 32 // fit_kernel.k_tile(32, (16, 16, 16)) == 2
+    planes, counts = fit_kernel.fitmask_multibox_counts(
+        jnp.array(occ), boxes, interpret=True)
+    np.testing.assert_array_equal(
+        np.asarray(planes), fit_ops.get_engine("numpy").multibox(occ, boxes))
+    np.testing.assert_array_equal(np.asarray(counts), occ.sum(axis=(1, 2, 3)))
+
+
 def test_fitmask_batched_cubes_use_case():
     """The reconfig allocator's batched per-cube check."""
     rng = np.random.default_rng(0)

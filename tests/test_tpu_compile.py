@@ -14,6 +14,8 @@ library.
 from __future__ import annotations
 
 import functools
+import json
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -24,6 +26,20 @@ from repro.kernels.fitmask import kernel
 from repro.kernels.fitmask.ops import JaxEngine
 
 GRIDS = [(1, 16, 16, 16), (64, 4, 4, 4)]
+ROOT = Path(__file__).resolve().parents[1]
+
+
+def _warmed(config):
+    """The (B, K) multibox shapes a benchmark configuration warms for
+    its what-if cell, with its grid."""
+    warm = json.loads((ROOT / "bench/configs" / f"{config}.json")
+                      .read_text())["warm"]["whatif"]
+    return [(b, k, tuple(warm["grid"])) for b, k in warm["multibox"]]
+
+
+# Every shape the static 16^3 what-if cell warms (its K is tiled), and
+# the paper's pod at B = 64 and K = 64 (one tile).
+MULTIBOX = _warmed("folding16") + [(64, 64, (4, 4, 4))]
 # K = 8: the size the engine pads a small candidate set to.
 BOXES = ((1, 1, 1), (2, 2, 2), (4, 4, 4), (4, 2, 1), (2, 4, 4),
          (3, 1, 2), (1, 4, 4), (8, 2, 1))
@@ -74,3 +90,17 @@ def test_jax_engine_bucket_program_compiles_for_v5e(one_chip, grid):
     compiled = JaxEngine._bucket_fn(BOXES).lower(
         _occ(grid, one_chip, jnp.bool_)).compile()
     assert compiled.as_text()
+
+
+@pytest.mark.parametrize("b,k,grid", MULTIBOX,
+                         ids=lambda v: "x".join(map(str, v))
+                         if isinstance(v, tuple) else str(v))
+def test_warmed_multibox_shapes_compile_for_v5e(one_chip, b, k, grid):
+    """Planes and counts at every warmed (B, K): without K tiles, any
+    B >= 8 with K >= 128 on 16^3 ran out of VMEM."""
+    fn = functools.partial(kernel.fitmask_multibox_counts,
+                           boxes=((1, 1, 1),) * k, interpret=False)
+    compiled = jax.jit(fn).lower(_occ((b,) + grid, one_chip)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    tiles = k // kernel.k_tile(k, grid)
+    assert tiles == (1 if grid == (4, 4, 4) else max(1, k // 16))
