@@ -303,7 +303,7 @@ class _ReconfigBase(PlacementPolicy):
 
     def try_place(self, job_id: int, shape: JobShape) -> Optional[Placement]:
         with obs.span("plan.search") as sp:
-            visited = pruned = 0
+            visited = pruned = rows = rows_cut = 0
             if self.use_naive:
                 best: Optional[ReconfigPlan] = None
                 for fold in self._folds(shape):
@@ -323,9 +323,10 @@ class _ReconfigBase(PlacementPolicy):
                 # (repro.core.reconfig.plan_search).
                 best = self.cluster.plan_search(
                     self._folds(shape), offset_search=self.offset_search)
-                visited, pruned = self.cluster.last_search
+                visited, pruned, rows, rows_cut = self.cluster.last_search
             if sp.recording:
                 sp.tag(policy=self.name, folds=visited, pruned=pruned,
+                       rows=rows, rows_cut=rows_cut,
                        placed=best is not None)
             if best is None:
                 return None

@@ -60,6 +60,8 @@ from .geometry import Coord, Dims, volume
 from .torus import FaultConflictError
 
 Slice3 = Tuple[Tuple[int, int], Tuple[int, int], Tuple[int, int]]  # half-open
+# Supply of a local sub-block whose fit mask is not at hand: no limit.
+_SUPPLY_UNKNOWN = np.iinfo(np.int32).max
 
 
 @functools.lru_cache(maxsize=None)
@@ -123,6 +125,58 @@ def _offset_table_cached(box: Dims, n: int):
     return offs, ncubes, links, wrapcode
 
 
+@functools.lru_cache(maxsize=None)
+def _axis_demand(ext: int, n: int):
+    """One axis of the supply test's demand: the distinct cube-local
+    spans of an extent over all its corner offsets, as (lo, length)
+    arrays, and a (offsets, spans) count of the chain's cubes that take
+    each span at each offset (the offset is its own row index)."""
+    per_off = [_axis_spans(ext, o, n)
+               for o in _offset_candidates_cached(ext, n)]
+    spans = sorted({sp for row in per_off for _, sp, _ in row})
+    col = {sp: j for j, sp in enumerate(spans)}
+    cnt = np.zeros((len(per_off), len(spans)), dtype=np.int32)
+    for i, row in enumerate(per_off):
+        for _, sp, _ in row:
+            cnt[i, col[sp]] += 1
+    lo = np.array([a for a, _ in spans], dtype=np.int64)
+    length = np.array([b - a for a, b in spans], dtype=np.int64)
+    return lo, length, cnt
+
+
+@dataclass
+class _RowDemand:
+    """Occupancy-free demand of a multi-cube fold's plan-table rows:
+    ``pieces[t, l]`` pieces of row ``t`` need local sub-block ``l``
+    (pieces need distinct cubes). ``keys[l]`` addresses the local's
+    supply as ``shape id * n³ + origin id``, and ``shapes`` lists each
+    distinct (shape id, shape) once."""
+
+    pieces: np.ndarray             # (O, L) int32
+    keys: np.ndarray               # (L,) int64
+    shapes: Tuple[Tuple[int, Dims], ...]
+
+
+def _row_demand(box: Dims, offs: np.ndarray, n: int) -> _RowDemand:
+    """Per-row demand from the per-axis span multiplicities: a local
+    (sx, sy, sz) is needed by cx(sx)·cy(sy)·cz(sz) pieces."""
+    (lx, nx, cx), (ly, ny, cy), (lz, nz, cz) = (
+        _axis_demand(e, n) for e in box)
+    cx, cy, cz = cx[offs[:, 0]], cy[offs[:, 1]], cz[offs[:, 2]]
+    pieces = (cx[:, :, None, None] * cy[:, None, :, None]
+              * cz[:, None, None, :]).reshape(len(offs), -1)
+    sid = (((nx - 1)[:, None, None] * n + (ny - 1)[None, :, None]) * n
+           + (nz - 1)[None, None, :]).ravel()
+    oid = ((lx[:, None, None] * n + ly[None, :, None]) * n
+           + lz[None, None, :]).ravel()
+    used = pieces.any(axis=0)
+    sid = sid[used]
+    shapes = tuple((int(s), (int(s) // (n * n) + 1, int(s) // n % n + 1,
+                             int(s) % n + 1)) for s in np.unique(sid))
+    return _RowDemand(pieces=pieces[:, used], keys=sid * n ** 3 + oid[used],
+                      shapes=shapes)
+
+
 @dataclass
 class _FoldPlanTable:
     """One fold's valid offset candidates at a fixed (cube size, cube
@@ -144,6 +198,9 @@ class _FoldPlanTable:
     # compares one row per iteration and python ints beat numpy
     # scalars there.
     prefix: List[Tuple[int, int, int]] = None  # type: ignore[assignment]
+    # Per-row sub-block demand for the supply test, built on a
+    # multi-cube fold's first search.
+    demand: Optional[_RowDemand] = None
 
     def __post_init__(self):
         self.prefix = list(zip(self.nbroken.tolist(), self.ncubes.tolist(),
@@ -297,9 +354,12 @@ class ReconfigTorus:
         self.occ = np.zeros((self.num_cubes,) + (cube_n,) * 3, dtype=bool)
         # cube dedicated to a multi-cube job's virtual torus (-1 = no)
         self.dedicated = np.full(self.num_cubes, -1, dtype=np.int64)
-        # (folds visited, folds pruned by the bound) of the last
-        # plan_search: tags of the caller's ``plan.search`` span.
-        self.last_search: Tuple[int, int] = (0, 0)
+        # (folds visited, folds pruned by the bound, offset rows handed
+        # to cube assignment, rows the supply test ruled out) of the
+        # last plan_search: tags of the caller's ``plan.search`` span.
+        self.last_search: Tuple[int, int, int, int] = (0, 0, 0, 0)
+        self._rows = 0                # running totals over place_fold
+        self._rows_cut = 0
         self.allocations: Dict[int, List[Piece]] = {}
         self.alloc_meta: Dict[int, dict] = {}
         # Fault state (chaos layer): failed cells are marked busy in
@@ -337,6 +397,14 @@ class ReconfigTorus:
         # shared batched integral image.
         self._seen_shapes: set = set()
         self._shape_masks: Dict[Dims, np.ndarray] = {}
+        # Per-epoch supply of the multi-cube supply test: eligible cubes
+        # per (sub-block shape, origin), flat at ``shape id * n³ +
+        # origin id``; ``_SUPPLY_UNKNOWN`` where the shape's fit mask
+        # was not at hand. ``_supply_have`` holds the shape ids filled
+        # this epoch.
+        self._supply: Optional[np.ndarray] = None
+        self._supply_have: set = set()
+        self._supply_elig: Optional[np.ndarray] = None   # (C,) int32 0/1
 
     # ------------------------------------------------------------------
     def set_mask_client(self, client) -> None:
@@ -454,6 +522,11 @@ class ReconfigTorus:
         self._elig_order = None
         self._engine = client
         self._sorted_cands = {}
+        if self._supply_have:
+            self._supply.reshape(n3, n3)[list(self._supply_have)] = \
+                _SUPPLY_UNKNOWN
+            self._supply_have = set()
+        self._supply_elig = None
         self._dirty = set()
         self._cache_epoch = self._epoch
 
@@ -643,11 +716,19 @@ class ReconfigTorus:
         # (Reconfig) baseline pins chained pieces to the cube corner.
         if all(ext <= n for ext in box):
             return self._place_single_cube(fold, tab, bound)
+        if not offset_search and tab.pinned_pos is None:
+            return None
+        # Only rows that pass the supply test can place (see
+        # :meth:`_supply_ok`); ruling the others out leaves the plan
+        # unchanged, since the walk below only moves on rows that place.
+        ok = self._supply_ok(fold, tab)
         if offset_search:
-            positions = range(len(tab.offsets))
-        elif tab.pinned_pos is not None:
-            positions = (tab.pinned_pos,)
+            positions = np.flatnonzero(ok).tolist()
+            self._rows_cut += len(ok) - len(positions)
+        elif ok[tab.pinned_pos]:
+            positions = [tab.pinned_pos]
         else:
+            self._rows_cut += 1
             return None
         best: Optional[ReconfigPlan] = None
         incumbent = bound
@@ -669,6 +750,7 @@ class ReconfigTorus:
                 # their fresh bound skip cube assignment entirely.
                 if (nb, nc, lk, fresh_lb) >= incumbent:
                     continue
+            self._rows += 1
             plan = self._assign_plan(fold, tab, t)
             if plan is None:
                 continue
@@ -682,6 +764,47 @@ class ReconfigTorus:
                 if score[3] == fresh_lb:
                     break
         return best
+
+    def _supply_ok(self, fold: Fold, tab: _FoldPlanTable) -> np.ndarray:
+        """Bool per plan-table row of a multi-cube fold: False where some
+        local sub-block is needed by more of the row's pieces than there
+        are cubes eligible for it, as :meth:`_cands_for` decides (a fit
+        mask at that origin, or an empty cube when chained; not
+        dedicated; a working OCS port). Pieces need distinct cubes, so
+        :meth:`_assign_plan` returns None on such a row.
+
+        A shape's supply is counted once per epoch, from its fit mask
+        only if that is already at hand: a local without one counts as
+        unlimited, so no mask is computed for the test and it can only
+        rule out fewer rows."""
+        elig = self._supply_elig
+        if elig is None:
+            elig = self.dedicated < 0
+            if not self.ocs_ok.all():
+                elig = elig & self.ocs_ok
+            elig = self._supply_elig = elig.astype(np.int32)
+        if self.dedicate_chained:
+            # Every piece is chained and draws on the same empty cubes.
+            return tab.ncubes <= int(np.dot(elig, self._cube_empty))
+        dem = tab.demand
+        if dem is None:
+            dem = tab.demand = _row_demand(fold.box, tab.offs_arr,
+                                           self.cube_n)
+        n3 = self.cube_n ** 3
+        sup = self._supply
+        if sup is None:
+            sup = self._supply = np.full(n3 * n3, _SUPPLY_UNKNOWN,
+                                         dtype=np.int32)
+        have = self._supply_have
+        masks = self._shape_masks
+        for sid, shape in dem.shapes:
+            if sid not in have:
+                m = masks.get(shape)
+                if m is not None:
+                    sup[sid * n3:(sid + 1) * n3] = np.dot(
+                        elig, m.reshape(len(elig), n3))
+                    have.add(sid)
+        return (dem.pieces <= sup[dem.keys]).all(axis=1)
 
     def _place_single_cube(self, fold: Fold, tab: _FoldPlanTable,
                            bound: Optional[Tuple]) -> Optional[ReconfigPlan]:
@@ -772,6 +895,7 @@ class ReconfigTorus:
         bound: Optional[Tuple] = None
         n = self.cube_n
         visited = pruned = 0
+        rows0, cut0 = self._rows, self._rows_cut
         for fold in folds:
             if bound is not None and fold_score_bound(fold, n) >= bound:
                 pruned += 1
@@ -784,7 +908,8 @@ class ReconfigTorus:
             if bound is None or plan.score() < bound:
                 best = plan
                 bound = plan.score()
-        self.last_search = (visited, pruned)
+        self.last_search = (visited, pruned, self._rows - rows0,
+                            self._rows_cut - cut0)
         return best
 
     def place_fold_naive(self, fold: Fold,

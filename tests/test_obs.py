@@ -15,6 +15,8 @@ import numpy as np
 
 from repro import obs
 from repro.api import Scheduler, SchedulerConfig
+from repro.core.allocator import make_policy
+from repro.core.geometry import JobShape
 from repro.kernels.fitmask import ops
 from repro.sim.fleet import QueryBroker
 
@@ -105,11 +107,33 @@ def test_served_op_records_its_spans_down_to_the_device(tmp_path):
     for apply in (r for r in recs if r.name == "core.apply"):
         under = [r for r in searches if r.parent == apply.sid]
         assert apply.tags["searches"] == len(under)
-    assert all({"folds", "pruned", "placed"} <= set(r.tags)
-               for r in searches)
+    assert all({"folds", "pruned", "rows", "rows_cut", "placed"}
+               <= set(r.tags) for r in searches)
     assert any(r.tags["placed"] for r in searches)
     snaps = [r for r in recs if r.name == "wal.snapshot"]
     assert all(r.tags["bytes"] > 0 and r.tags["records"] > 0 for r in snaps)
+
+
+def test_plan_search_tags_rows_and_rows_cut(tmp_path):
+    """A multi-cube search tags ``plan.search`` with the offset rows it
+    handed to cube assignment and the rows the supply test ruled out. A
+    failed corner cell leaves no cube whole: the first 8x4x4 search
+    reads the whole-cube fit mask while assigning its one row, so the
+    second, in the same occupancy epoch, rules that row out unassigned."""
+    pol = make_policy("rfold", **MEDIUM)
+    cl = pol.cluster
+    cl.fail_cells([(c, 0, 0, 0) for c in range(cl.num_cubes)])
+    assigned = []
+    assign = cl._assign_plan
+    cl._assign_plan = lambda *a: assigned.append(a) or assign(*a)
+    t0 = time.perf_counter()
+    with _profiled(tmp_path):
+        for jid, dims in enumerate([(8, 4, 4), (8, 4, 4), (8, 8, 2)]):
+            pol.try_place(jid, JobShape(dims))
+    searches = [r.tags for r in _since(t0) if r.name == "plan.search"]
+    assert [(t["rows"], t["rows_cut"], t["placed"]) for t in searches] == [
+        (1, 0, False), (0, 1, False), (2, 1, True)]
+    assert sum(t["rows"] for t in searches) == len(assigned)
 
 
 def test_tracing_leaves_the_schedule_unchanged(tmp_path):

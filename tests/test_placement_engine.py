@@ -102,17 +102,46 @@ def _random_fill(rt: ReconfigTorus, rng, steps=18):
     return live
 
 
-@pytest.mark.parametrize("seed", [0, 1, 2, 3])
+# (seed, num_xpus, cube_n, variant): the first four are 4^3 cubes as
+# they always were; the rest add cube sizes 2 and 8, the chained-cube
+# ablation and failed OCS ports.
+PARITY_CASES = [pytest.param(s, 512, 4, "plain", id=str(s)) for s in range(4)] + [
+    pytest.param(4, 512, 2, "plain", id="4-n2"),
+    pytest.param(5, 4096, 8, "plain", id="5-n8"),
+    pytest.param(6, 512, 4, "chained", id="6-chained"),
+    pytest.param(7, 512, 2, "chained", id="7-n2-chained"),
+    pytest.param(8, 512, 4, "ocs", id="8-ocs"),
+    pytest.param(9, 4096, 8, "ocs", id="9-n8-ocs"),
+]
+
+
+@pytest.mark.parametrize("seed,num_xpus,cube_n,variant", PARITY_CASES)
 @pytest.mark.parametrize("offset_search", [True, False])
-def test_place_fold_parity_random_occupancy(seed, offset_search):
+def test_place_fold_parity_random_occupancy(seed, num_xpus, cube_n, variant,
+                                            offset_search):
+    """place_fold == the naive oracle, with no bound and against an
+    incumbent's score (then only a strictly better plan comes back)."""
     rng = np.random.default_rng(seed)
-    rt = ReconfigTorus(512, 4)
+    rt = ReconfigTorus(num_xpus, cube_n, dedicate_chained=variant == "chained")
+    if variant == "ocs":
+        rt.fail_ocs_port(rng.choice(rt.num_cubes, size=rt.num_cubes // 4,
+                                    replace=False))
     _random_fill(rt, rng)
     for dims in [(8, 4, 4), (18, 1, 1), (4, 8, 2), (6, 6, 1), (3, 3, 3),
-                 (16, 2, 2)]:
+                 (16, 2, 2), (12, 8, 4)]:
+        incumbent = None
         for f in enumerate_folds(JobShape(dims), max_dim=rt.max_extent):
-            assert rt.place_fold(f, offset_search=offset_search) == \
-                rt.place_fold_naive(f, offset_search=offset_search), (dims, f)
+            want = rt.place_fold_naive(f, offset_search=offset_search)
+            assert rt.place_fold(f, offset_search=offset_search) == want, \
+                (dims, f)
+            if incumbent is not None:
+                beats = (want if want is not None and want.score() < incumbent
+                         else None)
+                assert rt.place_fold(f, offset_search=offset_search,
+                                     bound=incumbent) == beats, (dims, f)
+            if want is not None and (incumbent is None
+                                     or want.score() < incumbent):
+                incumbent = want.score()
 
 
 @pytest.mark.parametrize("name,kw", [("reconfig", dict(num_xpus=512, cube_n=4)),

@@ -15,11 +15,23 @@ from repro.core import fitmask
 from repro.core.allocator import make_policy
 from repro.core.folding import enumerate_folds
 from repro.core.geometry import JobShape
-from repro.core.reconfig import ReconfigTorus, fold_plan_table
+from repro.core.reconfig import ReconfigTorus, _row_demand, fold_plan_table
 from repro.sim.simulator import Simulator
 from repro.traces.generator import TraceConfig, generate_trace
 
 CUBE_SIZES = [(512, 2), (512, 4), (4096, 8)]
+# Cluster variants: as built, the chained-cube ablation, and a quarter
+# of the cubes with a failed OCS port.
+VARIANTS = ["plain", "chained", "ocs"]
+
+
+def _torus(size, variant, rng) -> ReconfigTorus:
+    num_xpus, cube_n = size
+    rt = ReconfigTorus(num_xpus, cube_n, dedicate_chained=variant == "chained")
+    if variant == "ocs":
+        rt.fail_ocs_port(rng.choice(rt.num_cubes, size=rt.num_cubes // 4,
+                                    replace=False))
+    return rt
 
 
 def _random_fill(rt: ReconfigTorus, rng, steps=14):
@@ -47,18 +59,64 @@ def _random_fill(rt: ReconfigTorus, rng, steps=14):
        st.integers(0, 10_000),
        st.tuples(st.integers(1, 12), st.integers(1, 12),
                  st.integers(1, 12)),
-       st.sampled_from([True, False]))
-def test_place_fold_parity_sweep(size, seed, dims, offset_search):
+       st.sampled_from([True, False]),
+       st.sampled_from(VARIANTS))
+def test_place_fold_parity_sweep(size, seed, dims, offset_search, variant):
     """Batched place_fold == naive oracle for every fold of a random
-    shape on a randomly filled torus, across cube sizes and offset
-    modes."""
-    num_xpus, cube_n = size
+    shape on a randomly filled torus, across cube sizes, offset modes
+    and cluster variants; against an incumbent's score (the best plan
+    of the folds before it), only a strictly better plan comes back."""
     rng = np.random.default_rng(seed)
-    rt = ReconfigTorus(num_xpus, cube_n)
+    rt = _torus(size, variant, rng)
     _random_fill(rt, rng, steps=10)
+    incumbent = None
     for f in enumerate_folds(JobShape(dims), max_dim=rt.max_extent):
-        assert rt.place_fold(f, offset_search=offset_search) == \
-            rt.place_fold_naive(f, offset_search=offset_search), (dims, f)
+        want = rt.place_fold_naive(f, offset_search=offset_search)
+        assert rt.place_fold(f, offset_search=offset_search) == want, \
+            (dims, f)
+        if incumbent is not None:
+            beats = (want if want is not None and want.score() < incumbent
+                     else None)
+            assert rt.place_fold(f, offset_search=offset_search,
+                                 bound=incumbent) == beats, (dims, f)
+        if want is not None and (incumbent is None
+                                 or want.score() < incumbent):
+            incumbent = want.score()
+
+
+@pytest.mark.parametrize("variant", VARIANTS)
+@pytest.mark.parametrize("size", CUBE_SIZES, ids=lambda s: "%d-%d" % s)
+def test_supply_test_rules_out_only_unplaceable_rows(size, variant):
+    """Every multi-cube row the supply test rules out is a row on which
+    cube assignment fails, with every fit mask the test may read at
+    hand; and on random occupancies the test does rule rows out."""
+    cut = 0
+    for seed in range(4):
+        rng = np.random.default_rng(seed)
+        rt = _torus(size, variant, rng)
+        n = rt.cube_n
+        target = rng.uniform(0.2, 0.8)     # occupancy to fill up to
+        for jid in range(400):
+            if rt.utilization() >= target:
+                break
+            dims = JobShape(tuple(int(v) for v in rng.integers(1, n + 1, 3)))
+            plan = rt.plan_search(enumerate_folds(dims,
+                                                  max_dim=rt.max_extent))
+            if plan is not None:
+                rt.commit(jid, plan)
+        for dims in [(8, 4, 4), (16, 2, 2), (6, 6, 2), (12, 8, 4),
+                     (10, 3, 2)]:
+            for f in enumerate_folds(JobShape(dims), max_dim=rt.max_extent):
+                tab = fold_plan_table(f, n, rt.num_cubes)
+                if tab is None or all(ext <= n for ext in f.box):
+                    continue
+                for _, shape in _row_demand(f.box, tab.offs_arr, n).shapes:
+                    rt._shape_fit_mask(shape)
+                ok = rt._supply_ok(f, tab)
+                for t in np.flatnonzero(~ok).tolist():
+                    assert rt._assign_plan(f, tab, t) is None, (f, t)
+                cut += int((~ok).sum())
+    assert cut > 0
 
 
 @settings(max_examples=15, deadline=None)
