@@ -61,7 +61,7 @@ def _run_matrix(configs, runs: int, num_jobs: int, load: float,
     runner = EvalRunner(checkpoint_dir=ckpt_dir, workers=workers,
                         emit=emit, fleet_size=fleet_size)
     records = runner.run(tasks)
-    return aggregate_by_label(records), runner.last_stats, tasks
+    return aggregate_by_label(records), runner, tasks
 
 
 def _legacy_aggs(aggs: Dict[str, Dict]) -> Dict[str, Dict]:
@@ -217,11 +217,12 @@ def main(argv=None) -> None:
             os.remove(path)
 
     t0 = time.time()
-    aggs, stats, tasks = _run_matrix(_configs_for(args.which), runs, n,
-                                     args.load, args.seed0, workers,
-                                     ckpt_dir, trace_kw=trace_kw,
-                                     fleet_size=fleet_size,
-                                     scenario=args.scenario)
+    aggs, runner, tasks = _run_matrix(_configs_for(args.which), runs, n,
+                                      args.load, args.seed0, workers,
+                                      ckpt_dir, trace_kw=trace_kw,
+                                      fleet_size=fleet_size,
+                                      scenario=args.scenario)
+    stats = runner.last_stats
     if args.prune_ckpt and ckpt_dir and os.path.isdir(ckpt_dir):
         from repro.eval import prune_checkpoints
         max_bytes = (args.ckpt_max_mb * 1024 * 1024
@@ -254,7 +255,6 @@ def main(argv=None) -> None:
         with open(args.out, "w") as f:
             json.dump(results, f, indent=1, default=float)
     if bench_out:
-        from repro.kernels.fitmask import ops
         bench = {
             "config": {"runs": runs, "num_jobs": n, "load": args.load,
                        "seed0": args.seed0, "which": args.which,
@@ -266,7 +266,7 @@ def main(argv=None) -> None:
                        # the resolved size actually used (None: the
                        # sequential per-task oracle path ran)
                        "fleet_size": stats.get("fleet", {}).get("size"),
-                       "fitmask_engine": ops.default_engine_name()},
+                       "engine": runner.fleet_engine or "numpy"},
             "pool": stats,
             "wall_s": round(wall, 3),
             "per_policy_sim_s": {label: a["sim_s_total"]

@@ -31,9 +31,7 @@ from typing import Any, Dict, List, Optional
 # Engine selection (the one resolution point for fitmask engines) and
 # the runtime failover chain the fleet broker degrades down.
 from repro.core.engineconfig import (FAILOVER_CHAIN, EngineConfig,
-                                     default_engine_name,
-                                     failover_candidates,
-                                     set_default_engine)
+                                     failover_candidates)
 # Placement policies + geometry.
 from repro.core.allocator import POLICIES, Placement, PlacementPolicy, make_policy
 from repro.core.events import EventLog, TopologyEvent
@@ -63,8 +61,7 @@ __all__ = [
     "submit", "events", "start_scheduler", "stop_scheduler",
     "NOT_LEADER", "ROLE_PRIMARY", "ROLE_STANDBY",
     # engine selection + runtime failover
-    "EngineConfig", "set_default_engine", "default_engine_name",
-    "FAILOVER_CHAIN", "failover_candidates",
+    "EngineConfig", "FAILOVER_CHAIN", "failover_candidates",
     # placement
     "POLICIES", "make_policy", "PlacementPolicy", "Placement", "JobShape",
     "TopologyEvent", "EventLog",

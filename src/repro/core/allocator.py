@@ -98,12 +98,11 @@ class PlacementPolicy:
 # ----------------------------------------------------------------------
 
 class _StaticBase(PlacementPolicy):
-    def __init__(self, dims: Dims = (16, 16, 16),
-                 fitmask_engine: Optional[str] = None,
-                 engine=None, mask_client=None):
+    def __init__(self, dims: Dims = (16, 16, 16), engine=None,
+                 mask_client=None):
         super().__init__()
-        self.torus = StaticTorus(dims, fitmask_engine=fitmask_engine,
-                                 engine=engine, mask_client=mask_client)
+        self.torus = StaticTorus(dims, engine=engine,
+                                 mask_client=mask_client)
 
     def _candidate_boxes(self, folds) -> List[Dims]:
         """Distinct in-bounds fold boxes — one allocator step's fit-mask
@@ -259,12 +258,10 @@ class FoldingPolicy(_StaticBase):
 class _ReconfigBase(PlacementPolicy):
     def __init__(self, num_xpus: int = 4096, cube_n: int = 4,
                  dedicate_chained: bool = False,
-                 fitmask_engine: Optional[str] = None,
                  engine=None, mask_client=None):
         super().__init__()
         self.cluster = ReconfigTorus(num_xpus, cube_n,
                                      dedicate_chained=dedicate_chained,
-                                     fitmask_engine=fitmask_engine,
                                      engine=engine, mask_client=mask_client)
 
     @property
@@ -409,11 +406,9 @@ class RFoldBestEffortPolicy(RFoldPolicy):
     def __init__(self, num_xpus: int = 4096, cube_n: int = 4,
                  dedicate_chained: bool = False,
                  scatter_slowdown: float = 1.5,
-                 fitmask_engine: Optional[str] = None,
                  engine=None, mask_client=None):
         super().__init__(num_xpus, cube_n,
                          dedicate_chained=dedicate_chained,
-                         fitmask_engine=fitmask_engine,
                          engine=engine, mask_client=mask_client)
         self.scatter_slowdown = scatter_slowdown
 

@@ -1,38 +1,20 @@
-"""Typed engine selection — resolved in exactly one place.
+"""Typed engine selection: which fitmask backend, driven how.
 
-Engine choice used to be scattered: a ``fitmask_engine`` string kwarg
-threaded through every torus/policy constructor, ``fleet_size``/
-``fleet_engine``/``fleet_quorum``/``fleet_timeout`` kwargs on the eval
-runner, the ``REPRO_FITMASK_ENGINE`` environment variable consulted
-deep inside the registry, and a process-global ``set_default_engine``.
-Each call site re-implemented the precedence order, and nothing typed
-tied "which backend" to "how the fleet broker drives it".
+The engine is named once, where a torus, a query broker or a daemon is
+built, and nothing in the process changes it afterwards. An unnamed
+selection means ``numpy``; a name is checked by the engine registry
+(``repro.kernels.fitmask.ops.engine_name``), which is the one place a
+name becomes an engine. :class:`EngineConfig` carries the name together
+with how the fleet and service layers drive it.
 
-:class:`EngineConfig` is the one value that carries both, and
-:meth:`EngineConfig.resolve_name` is the **single** place the
-precedence order lives:
-
-    explicit ``engine`` field
-      > :func:`set_default_engine` (process-wide programmatic default)
-      > ``REPRO_FITMASK_ENGINE`` env var (**deprecated** alias — warns
-        once per process)
-      > ``"numpy"``
-
-``repro.kernels.fitmask.ops`` delegates its historical
-``set_default_engine``/``default_engine_name`` entry points here, so
-the old spellings keep working while the logic exists once. This
-module imports neither jax nor the engine registry at import time (the
-registry is consulted lazily) so the numpy-purity of the host path is
-preserved.
+This module imports neither jax nor the engine registry at import time
+(the registry is consulted lazily) so the numpy-purity of the host path
+is preserved.
 """
 from __future__ import annotations
 
-import os
-import warnings
-from dataclasses import dataclass, replace
+from dataclasses import dataclass
 from typing import Optional, Union
-
-ENGINE_ENV = "REPRO_FITMASK_ENGINE"
 
 # Failover order (PR 9): when a compiled engine starts raising at
 # runtime, the fleet broker degrades *down* this chain — each step
@@ -49,69 +31,12 @@ def failover_candidates(name: str) -> tuple:
     unknown names also return empty (a custom engine *instance* has no
     registry identity, so the broker never fails it over — errors
     propagate, preserving the historical contract)."""
-    try:
-        name = canonical_engine_name(name)
-    except KeyError:
+    from repro.kernels.fitmask import ops  # lazy: numpy-only either way
+    if name not in ops.available_engines():
         return ()
     if name in FAILOVER_CHAIN:
         return FAILOVER_CHAIN[FAILOVER_CHAIN.index(name) + 1:]
     return ("numpy",)
-
-# Process-wide programmatic default (the ``set_default_engine`` knob).
-_default_engine: Optional[str] = None
-# The env var warns once per process, not once per query.
-_env_warned = False
-
-
-def canonical_engine_name(name: str) -> str:
-    """Alias-fold and validate an engine name against the registry.
-    Raises ``KeyError`` (the registry's historical contract) on an
-    unknown name."""
-    from repro.kernels.fitmask import ops  # lazy: numpy-only either way
-    name = ops._ALIASES.get(name, name)
-    if name not in ops._REGISTRY:
-        raise KeyError(f"unknown fitmask engine {name!r}; "
-                       f"have {ops.available_engines()}")
-    return name
-
-
-def set_default_engine(name: Optional[str]) -> None:
-    """Process-wide default engine (overrides the deprecated env var);
-    ``None`` resets to env-var/``numpy`` resolution."""
-    global _default_engine
-    if name is not None:
-        name = canonical_engine_name(name)
-    _default_engine = name
-
-
-def _env_engine() -> Optional[str]:
-    """The deprecated ``REPRO_FITMASK_ENGINE`` escape hatch; warns on
-    first use. An unknown value raises ``KeyError`` eagerly — a typo'd
-    env var must not silently fall back to numpy."""
-    env = os.environ.get(ENGINE_ENV, "").strip()
-    if not env:
-        return None
-    global _env_warned
-    if not _env_warned:
-        warnings.warn(
-            f"{ENGINE_ENV} is deprecated; pass "
-            "EngineConfig(engine=...) (or engine=/fitmask_engine= "
-            "kwargs) or call set_default_engine() instead",
-            DeprecationWarning, stacklevel=3)
-        _env_warned = True
-    from repro.kernels.fitmask import ops
-    name = ops._ALIASES.get(env, env)
-    if name not in ops._REGISTRY:
-        raise KeyError(f"{ENGINE_ENV}={env!r} names no engine; "
-                       f"have {ops.available_engines()}")
-    return name
-
-
-def default_engine_name() -> str:
-    """The registry's resolved default — ``EngineConfig().resolve_name()``."""
-    if _default_engine is not None:
-        return _default_engine
-    return _env_engine() or "numpy"
 
 
 @dataclass(frozen=True)
@@ -119,9 +44,8 @@ class EngineConfig:
     """One typed value for "which fitmask backend, driven how".
 
     ``engine``
-        Registry name (``numpy``/``jax``/``pallas``/``ref`` or an
-        alias). ``None`` defers to the process default / deprecated
-        env var / ``numpy``.
+        Registry name (``numpy``/``jax``/``pallas``/``ref``); ``None``
+        means ``numpy``.
     ``fleet_size`` / ``quorum`` / ``timeout`` / ``max_inflight``
         How the fleet/service layers drive the backend: simulators per
         broker and the broker's flush policy. ``"auto"`` defers to the
@@ -147,35 +71,8 @@ class EngineConfig:
         raise TypeError("engine selection must be None, an engine name "
                         f"or an EngineConfig, got {value!r}")
 
-    def with_engine(self, name: Optional[str]) -> "EngineConfig":
-        return replace(self, engine=name)
-
-    # -- THE selection point ------------------------------------------
     def resolve_name(self) -> str:
-        """Resolve to a concrete registry name. Explicit field first,
-        then :func:`set_default_engine`, then the deprecated env var,
-        then ``numpy``."""
-        if self.engine is not None:
-            return canonical_engine_name(self.engine)
-        return default_engine_name()
-
-    def get_engine(self):
-        """The resolved :class:`~repro.kernels.fitmask.ops.FitmaskEngine`
-        singleton."""
+        """The registry name: the explicit one, checked by the registry
+        (``KeyError`` if unknown), else ``numpy``."""
         from repro.kernels.fitmask import ops
-        return ops.get_engine(self.resolve_name())
-
-    def make_client(self):
-        """Inline mask client for the resolved engine, or ``None`` for
-        the numpy host integral-image path (which stays free of
-        indirection — see ``repro.core.maskquery``)."""
-        from .maskquery import resolve_mask_client
-        return resolve_mask_client(self)
-
-    def fleet_kwargs(self) -> dict:
-        """Kwargs for ``repro.sim.fleet.Fleet``/``QueryBroker``."""
-        kw = {"engine": self.engine, "quorum": self.quorum,
-              "timeout": self.timeout}
-        if self.max_inflight is not None:
-            kw["max_inflight"] = self.max_inflight
-        return kw
+        return ops.engine_name(self.engine)

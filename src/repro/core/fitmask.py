@@ -188,8 +188,8 @@ def fit_mask_multi_fast(occ: np.ndarray, boxes: Sequence[Dims],
       inclusion/exclusion, and each ``== 0`` writes straight into the
       padded output plane — no intermediate full-size temporaries.
 
-    Parity with the oracle is property-tested in
-    ``tests/test_fitmask_engines.py``.
+    Parity with the oracle is property-tested
+    (``test_fit_mask_multi_fast_matches_oracle``).
     """
     occ = np.asarray(occ)
     bsz = occ.shape[0]

@@ -1,21 +1,17 @@
 """Request/response interface between torus models and fitmask engines.
 
-The toruses used to call a resolved fitmask engine inline
-(``engine.multibox(...)`` at the query site). That shape made every
-simulator a private engine owner: batch-1 calls, one engine pass per
-simulator per epoch, and the multi-box kernel's grid-batch axis (the
-``B`` in ``(B, K, X, Y, Z)``) never saw more than one simulator's
-occupancy. This module splits the call path into an explicit
-request/response contract so a torus *submits* its per-epoch mask work
-to whatever client is installed:
+A torus *submits* its per-epoch mask work to one client, fixed when the
+torus is built:
 
-  * :class:`InlineMaskClient` — the default: answers immediately from
-    one engine (exactly the old inline behaviour, same arrays).
-  * ``repro.sim.fleet.QueryBroker`` — the fleet layer's client:
-    blocks the submitting simulator, coalesces concurrent requests
-    from many simulators, and answers them all with genuinely batched
-    engine calls (grids stacked on the B axis, candidate boxes
-    unioned on K).
+  * :class:`InlineMaskClient` — answers immediately from one engine,
+    the registry's instance for the engine the torus was named with.
+  * ``repro.sim.fleet.QueryBroker`` — the fleet layer's client, passed
+    in as ``mask_client=``: blocks the submitting simulator, coalesces
+    concurrent requests from many simulators, and answers them all
+    with genuinely batched engine calls (grids stacked on the B axis,
+    candidate boxes unioned on K).
+  * ``None`` — the numpy engine: integral images built directly
+    inside the torus, with no engine call and no indirection.
 
 The contract is deliberately tiny — the two primitives every policy
 reduces to:
@@ -35,14 +31,10 @@ Both return host numpy arrays: callers index and cache them without
 engine-specific conversions. Answers are a pure function of
 ``(occ[b], box)`` per plane, which is what makes any batching client
 bit-exact with the inline path (see DESIGN.md §Fleet-batched eval).
-
-The numpy *host* path (integral images built directly inside the
-torus) is still represented by ``None`` — it is not an engine call
-and stays free of this indirection unless a client is installed.
 """
 from __future__ import annotations
 
-from typing import Dict, Optional, Sequence, Tuple
+from typing import Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -72,9 +64,9 @@ class MaskQueryClient:
 
 class InlineMaskClient(MaskQueryClient):
     """Answers requests immediately from one fitmask engine — the
-    single-simulator path, byte-identical to the pre-client inline
-    calls (it is the same engine invocation plus the same numpy
-    conversion the call sites used to do)."""
+    single-simulator path. The engine's methods are looked up at each
+    call, so a method set on the registry's instance after the torus
+    was built is what answers."""
 
     def __init__(self, engine):
         self.engine = engine
@@ -87,27 +79,15 @@ class InlineMaskClient(MaskQueryClient):
         return np.asarray(self.engine.free_counts(occ)).astype(np.int64)
 
 
-# Inline clients are interned per engine instance: `client is` identity
-# then doubles as "same backend as last epoch" in the torus caches
-# (engines themselves are singletons in the registry).
-_INLINE: Dict[int, InlineMaskClient] = {}
-
-
 def resolve_mask_client(selection=None) -> Optional[InlineMaskClient]:
-    """Resolve an engine selection to an inline client: ``None`` for
-    the builtin numpy host path (which must stay free of indirection
-    and jax imports), a cached :class:`InlineMaskClient` otherwise.
-    ``selection`` is an engine name, an
-    :class:`~repro.core.engineconfig.EngineConfig`, or ``None`` — all
-    resolved through ``EngineConfig.resolve_name()``, the single
-    selection point (set_default_engine > deprecated env var > numpy)."""
+    """The client a torus built with this engine selection (an engine
+    name, an :class:`~repro.core.engineconfig.EngineConfig`, or
+    ``None``) submits to when no ``mask_client`` is given: ``None`` for
+    numpy — the host path, free of indirection and jax imports — else
+    an :class:`InlineMaskClient` over the registry's engine instance."""
     from repro.core.engineconfig import EngineConfig
     from repro.kernels.fitmask import ops  # numpy-only at import time
     name = EngineConfig.coerce(selection).resolve_name()
-    if name == "numpy":
+    if name == ops.DEFAULT_ENGINE:
         return None
-    engine = ops.get_engine(name)
-    client = _INLINE.get(id(engine))
-    if client is None:
-        client = _INLINE[id(engine)] = InlineMaskClient(engine)
-    return client
+    return InlineMaskClient(ops.get_engine(name))

@@ -46,7 +46,6 @@ from __future__ import annotations
 
 import functools
 import itertools
-import warnings
 from dataclasses import dataclass
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -57,6 +56,7 @@ from . import fitmask
 from .engineconfig import EngineConfig
 from .folding import Fold, WrapFlags, verify_fold
 from .geometry import Coord, Dims, volume
+from .maskquery import resolve_mask_client
 from .torus import FaultConflictError
 
 Slice3 = Tuple[Tuple[int, int], Tuple[int, int], Tuple[int, int]]  # half-open
@@ -323,20 +323,19 @@ class ReconfigTorus:
 
     def __init__(self, num_xpus: int = 4096, cube_n: int = 4,
                  dedicate_chained: bool = False,
-                 fitmask_engine: Optional[str] = None,
                  engine=None, mask_client=None, listeners=None):
         if num_xpus % (cube_n ** 3):
             raise ValueError("num_xpus must be a multiple of cube volume")
-        # Free-block search backend: an EngineConfig / registry name /
-        # None for the resolved default (``fitmask_engine`` is the
-        # retained legacy spelling); "numpy" keeps the pure-host path.
-        self.engine_config = EngineConfig.coerce(
-            engine if engine is not None else fitmask_engine)
-        self.fitmask_engine = self.engine_config.engine
-        # Request/response client (repro.core.maskquery), injected at
-        # construction; the fleet layer points many clusters at one
-        # shared query broker.
-        self.mask_client = mask_client
+        # Free-block search backend: an EngineConfig or registry name;
+        # unnamed means numpy, the pure-host path.
+        self.engine_config = EngineConfig.coerce(engine)
+        # The request/response client (repro.core.maskquery) every
+        # sub-block freeness / free-count query is submitted to, chosen
+        # once: the injected one (the fleet layer points many clusters
+        # at one shared query broker), else the engine's own; None is
+        # the numpy host path.
+        self.mask_client = (mask_client if mask_client is not None
+                            else resolve_mask_client(self.engine_config))
         # Topology-event listeners (repro.core.events): notified on
         # every commit/release; OCS-wiring changes (multi-cube chains,
         # wrap closures) are flagged ``reconfigured`` so a scheduler
@@ -382,7 +381,6 @@ class ReconfigTorus:
         self._busy = 0
         self._cache_epoch = -1
         self._dirty: Optional[set] = None               # None = rebuild all
-        self._engine = None           # mask client resolved per refresh
         self._ii: Optional[np.ndarray] = None           # batched integral image
         self._free_cnt: Optional[np.ndarray] = None     # (C,) free cells/cube
         self._cube_empty: Optional[np.ndarray] = None   # (C,) bool
@@ -407,32 +405,6 @@ class ReconfigTorus:
         self._supply_elig: Optional[np.ndarray] = None   # (C,) int32 0/1
 
     # ------------------------------------------------------------------
-    def set_mask_client(self, client) -> None:
-        """Deprecated: pass ``mask_client=`` to the constructor (or to
-        ``make_policy``) instead. Delegates to the internal setter."""
-        warnings.warn(
-            "set_mask_client is deprecated; pass mask_client= to the "
-            "ReconfigTorus/policy constructor", DeprecationWarning,
-            stacklevel=2)
-        self._set_mask_client(client)
-
-    def _set_mask_client(self, client) -> None:
-        """Swap the request/response mask client: every sub-block
-        freeness / free-count query is *submitted* to it instead of
-        computed inline, even when the registry default is the numpy
-        host engine. ``None`` restores per-query engine resolution."""
-        self.mask_client = client
-        self._cache_epoch = -1     # cached masks belong to the old route
-        self._dirty = None
-
-    def _resolve_client(self):
-        """The client this cluster submits mask work to (None = the
-        numpy host integral-image path)."""
-        if self.mask_client is not None:
-            return self.mask_client
-        from .maskquery import resolve_mask_client
-        return resolve_mask_client(self.engine_config)
-
     def bump_epoch(self) -> None:
         """Invalidate cached occupancy-derived state (call after any
         direct mutation of ``occ``/``dedicated``)."""
@@ -466,10 +438,9 @@ class ReconfigTorus:
         if self._cache_epoch == self._epoch:
             return
         n3 = self.cube_n ** 3
-        client = self._resolve_client()
+        client = self.mask_client
         dirty = self._dirty
         partial = (dirty is not None and self._cache_epoch >= 0
-                   and client is self._engine
                    and len(dirty) * 4 <= self.num_cubes)
         if partial:
             d = np.fromiter(dirty, dtype=np.int64, count=len(dirty))
@@ -520,7 +491,6 @@ class ReconfigTorus:
         self._n_nonempty_elig = int(
             (~self._cube_empty & (self.dedicated < 0)).sum())
         self._elig_order = None
-        self._engine = client
         self._sorted_cands = {}
         if self._supply_have:
             self._supply.reshape(n3, n3)[list(self._supply_have)] = \
@@ -601,7 +571,7 @@ class ReconfigTorus:
         self._derived()
         m = self._shape_masks.get(shape)
         if m is None:
-            if self._engine is None:
+            if self.mask_client is None:
                 m = np.zeros(self.occ.shape, dtype=bool)
                 w = fitmask.window_sums_from_ii(self._ii, shape)
                 if w.size:
@@ -619,12 +589,12 @@ class ReconfigTorus:
                 # one epoch — so it stays lazy, like the no-client
                 # host path: ask only for the shape in hand.
                 self._seen_shapes.add(shape)
-                if getattr(self._engine, "host_free", False):
+                if getattr(self.mask_client, "host_free", False):
                     missing = [shape]
                 else:
                     missing = sorted(s for s in self._seen_shapes
                                      if s not in self._shape_masks)
-                out = self._engine.multibox(self.occ, missing)
+                out = self.mask_client.multibox(self.occ, missing)
                 for k, s in enumerate(missing):
                     self._shape_masks[s] = out[:, k] != 0
                 m = self._shape_masks[shape]

@@ -9,7 +9,6 @@ exclusivity on every commit.
 """
 from __future__ import annotations
 
-import warnings
 from dataclasses import dataclass, field
 from typing import Dict, FrozenSet, Iterable, List, Optional, Sequence, Tuple
 
@@ -61,41 +60,27 @@ class Allocation:
         return len(self.coords)
 
 
-def resolve_fitmask_engine(name: Optional[str]):
-    """Resolve a fitmask engine selection for the placement hot path:
-    ``None`` defers to the registry default (``REPRO_FITMASK_ENGINE``
-    env var / ``set_default_engine``). Returns ``None`` for ``numpy`` —
-    the builtin host integral-image fast path, which must stay free of
-    jax imports — and the engine object otherwise."""
-    client = maskquery.resolve_mask_client(name)
-    return None if client is None else client.engine
-
-
 class StaticTorus:
     """A D1×D2×D3 torus with full wrap-around on every axis whose size
     equals the torus dimension. Occupancy is a numpy bool grid.
 
-    ``engine`` selects the free-box search backend — an
-    :class:`~repro.core.engineconfig.EngineConfig`, a registry name, or
-    None for the resolved default (``fitmask_engine`` is the retained
-    legacy spelling): the default ``numpy`` engine keeps the host
-    integral-image path; accelerator engines answer all candidate boxes
-    of an epoch in one multi-box pass. ``mask_client`` injects a
-    request/response client (e.g. the fleet broker) at construction —
-    the post-hoc :meth:`set_mask_client` mutation is deprecated."""
+    ``engine`` names the free-box search backend — an
+    :class:`~repro.core.engineconfig.EngineConfig` or a registry name;
+    unnamed means ``numpy``, the host integral-image path. Accelerator
+    engines answer all candidate boxes of an epoch in one multi-box
+    pass. ``mask_client`` injects a request/response client (e.g. the
+    fleet broker) in place of the engine's own. The client is chosen
+    here, once, and never changes."""
 
-    def __init__(self, dims: Dims, fitmask_engine: Optional[str] = None,
-                 engine=None, mask_client=None, listeners=None):
+    def __init__(self, dims: Dims, engine=None, mask_client=None,
+                 listeners=None):
         self.dims: Dims = tuple(int(d) for d in dims)  # type: ignore[assignment]
-        self.engine_config = EngineConfig.coerce(
-            engine if engine is not None else fitmask_engine)
-        # Back-compat attribute: the raw engine selection (None = the
-        # resolved registry default), as call sites historically read.
-        self.fitmask_engine = self.engine_config.engine
-        # Request/response client (repro.core.maskquery), injected at
-        # construction. None: resolve per query from the engine config
-        # (engine registry / numpy host path).
-        self.mask_client: Optional[maskquery.MaskQueryClient] = mask_client
+        self.engine_config = EngineConfig.coerce(engine)
+        # The request/response client (repro.core.maskquery) every mask
+        # query is submitted to; None: the numpy host path.
+        self.mask_client: Optional[maskquery.MaskQueryClient] = (
+            mask_client if mask_client is not None
+            else maskquery.resolve_mask_client(self.engine_config))
         # Topology-event listeners (repro.core.events): notified on
         # every commit/release so a scheduler service can push
         # SETUP/RELEASE messages. Empty list = zero-cost.
@@ -130,31 +115,6 @@ class StaticTorus:
         self._box_masks: Dict[Dims, np.ndarray] = {}
 
     # ------------------------------------------------------------------
-    def set_mask_client(self, client) -> None:
-        """Deprecated: pass ``mask_client=`` to the constructor (or to
-        ``make_policy``) instead. Delegates to the internal setter."""
-        warnings.warn(
-            "set_mask_client is deprecated; pass mask_client= to the "
-            "StaticTorus/policy constructor", DeprecationWarning,
-            stacklevel=2)
-        self._set_mask_client(client)
-
-    def _set_mask_client(self, client) -> None:
-        """Swap the request/response mask client. With a client every
-        mask query rides the engine path — *submitted* instead of
-        computed inline — even when the registry default is the numpy
-        host engine. ``None`` restores per-query engine resolution."""
-        self.mask_client = client
-        self._fit_epoch = -1   # cached masks belong to the old route
-
-    def _resolve_client(self) -> Optional[maskquery.MaskQueryClient]:
-        """The client this torus submits mask work to: the installed
-        one, else the engine registry's inline client, else ``None``
-        (the numpy host integral-image path below)."""
-        if self.mask_client is not None:
-            return self.mask_client
-        return maskquery.resolve_mask_client(self.engine_config)
-
     def bump_epoch(self) -> None:
         """Invalidate cached occupancy-derived state (call after any
         direct mutation of ``occ``)."""
@@ -184,7 +144,7 @@ class StaticTorus:
         by a single multi-box pass per epoch (one VMEM integral image
         shared across the whole candidate set); the numpy path extracts
         windows from the shared host integral image."""
-        client = self._resolve_client()
+        client = self.mask_client
         if client is None:
             from . import fitmask
             m = np.zeros(self.dims, dtype=bool)
@@ -213,7 +173,7 @@ class StaticTorus:
         axis with work nobody reads this epoch). The numpy host path
         is already amortized by the shared integral image, so this is
         a no-op there."""
-        client = self._resolve_client()
+        client = self.mask_client
         if client is None:
             return
         self._fit_state()

@@ -247,9 +247,6 @@ def run_task(task: EvalTask, mask_client=None) -> Dict:
                       target_load=task.load,
                       **{**task.trace_kw, **(sc.trace_kw if sc else {})})
     jobs = generate_trace(cfg)
-    # Constructor injection: the client rides in with the policy
-    # rather than being bolted on post-construction (the deprecated
-    # install_mask_client dance).
     policy = make_policy(task.policy, mask_client=mask_client,
                          **task.policy_kw)
     sim_kw = dict(task.sim_kw)
@@ -329,10 +326,9 @@ def run_fleet_tasks(tasks: Sequence[EvalTask],
     records (task order) and the broker's coalescing stats.
 
     ``engine`` selects the broker's engine (registry name or
-    instance); the default follows the registry's selection order,
-    matching what the per-task path would have resolved. Note the
-    broker is the fleet's single engine: a per-task
-    ``fitmask_engine`` in ``policy_kw`` is overridden on this path
+    instance; ``None`` is numpy, as on the per-task path). Note the
+    broker is the fleet's single engine: a per-task ``engine`` in
+    ``policy_kw`` is overridden on this path
     (answers are bit-identical across engines, so records don't
     change — only where the masks get computed).
 
@@ -378,8 +374,8 @@ class EvalRunner:
     the host numpy path included (its multibox is genuinely (B, K)
     vectorized, see BENCH_fleet.json). ``None``/``0``/``1`` selects
     the per-task oracle path — records are byte-identical either way.
-    ``fleet_engine`` picks the brokers' engine (default: the
-    registry's selection order); ``fleet_quorum``/``fleet_timeout``
+    ``fleet_engine`` picks the brokers' engine (default: numpy);
+    ``fleet_quorum``/``fleet_timeout``
     tune the brokers' flush policy (``"auto"``: half-fleet quorum,
     engine-aware deadline).
     """
@@ -483,9 +479,8 @@ class EvalRunner:
         if fleet_size:
             selections = [self.fleet_engine]
         else:
-            selections = [tasks[i].policy_kw.get(
-                "engine", tasks[i].policy_kw.get("fitmask_engine"))
-                for i in pending]
+            selections = [tasks[i].policy_kw.get("engine")
+                          for i in pending]
         return any(EngineConfig.coerce(s).resolve_name() != "numpy"
                    for s in selections)
 

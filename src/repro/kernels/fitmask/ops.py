@@ -18,9 +18,9 @@ behind one registry and the allocator picks at runtime:
     is answered without a second device call.
   * ``ref``    — `jax.lax.reduce_window` oracle.
 
-Selection: an explicit ``engine=`` argument wins, then
-:func:`set_default_engine`, then the ``REPRO_FITMASK_ENGINE``
-environment variable, then ``numpy``. All engines share the contract
+Selection: :func:`get_engine` is the one place a name becomes an
+engine; an unnamed selection means ``numpy`` and an unknown name raises
+``KeyError``. All engines share the contract
 ``multibox(occ, boxes) -> (B, K, X, Y, Z) int32`` with every plane
 padded to the full grid (0 where the box overhangs or cannot fit), so
 callers never special-case engine, K, or infeasible boxes — plus
@@ -40,15 +40,9 @@ from typing import Dict, Optional, Sequence, Tuple, Type
 import numpy as np
 
 from repro import obs
-from repro.core import engineconfig as _engineconfig
 from repro.core import fitmask as np_engine
 
 Box = Tuple[int, int, int]
-
-# Selection order (explicit > set_default_engine > deprecated env var
-# > numpy) lives in repro.core.engineconfig — the single resolution
-# point; the names below are retained delegating spellings.
-ENGINE_ENV = _engineconfig.ENGINE_ENV
 
 # Compile-cache caps. Per-box window programs and per-bucket fused
 # programs are cached per distinct key; a long multi-shape sweep keeps
@@ -490,8 +484,8 @@ class RefEngine(FitmaskEngine):
 
 _REGISTRY: Dict[str, Type[FitmaskEngine]] = {}
 _INSTANCES: Dict[str, FitmaskEngine] = {}
-# Back-compat spellings from the pre-registry wrapper.
-_ALIASES = {"auto": "pallas", "kernel": "pallas"}
+# What an unnamed engine selection means.
+DEFAULT_ENGINE = "numpy"
 
 
 def register_engine(cls: Type[FitmaskEngine]) -> Type[FitmaskEngine]:
@@ -508,22 +502,22 @@ def available_engines() -> Tuple[str, ...]:
     return tuple(sorted(_REGISTRY))
 
 
-def set_default_engine(name: Optional[str]) -> None:
-    """Process-wide default (overrides the deprecated env var); None
-    resets to env-var/``numpy`` resolution. Delegates to
-    ``repro.core.engineconfig`` — the single selection point."""
-    _engineconfig.set_default_engine(name)
-
-
-def default_engine_name() -> str:
-    return _engineconfig.default_engine_name()
-
-
-def get_engine(name: Optional[str] = None) -> FitmaskEngine:
-    name = _ALIASES.get(name, name) if name else default_engine_name()
+def engine_name(name: Optional[str] = None) -> str:
+    """The registry name an engine selection means: ``None`` is
+    :data:`DEFAULT_ENGINE`; an unknown name raises ``KeyError``."""
+    if name is None:
+        return DEFAULT_ENGINE
     if name not in _REGISTRY:
         raise KeyError(f"unknown fitmask engine {name!r}; "
                        f"have {available_engines()}")
+    return name
+
+
+def get_engine(name: Optional[str] = None) -> FitmaskEngine:
+    """The process-wide instance of the named engine (``None``: numpy).
+    Methods are looked up on it at call time, so whatever is set on the
+    instance answers every client built from it."""
+    name = engine_name(name)
     inst = _INSTANCES.get(name)
     if inst is None:
         inst = _INSTANCES[name] = _REGISTRY[name]()
@@ -532,8 +526,7 @@ def get_engine(name: Optional[str] = None) -> FitmaskEngine:
 
 def fitmask(occ, box: Box, engine: Optional[str] = None):
     """occ: (B, X, Y, Z) or (X, Y, Z). Returns the int32 fit mask of
-    the same (batched) shape. ``engine=None`` follows the registry's
-    selection order (set_default_engine > env var > numpy). The numpy
+    the same (batched) shape. ``engine=None`` means numpy. The numpy
     engine returns a numpy array — no device round-trip; the ``jax``
     and ``pallas`` engines copy their answer back to the host too, so
     callers that want a jax array convert (or pick ``ref``)."""

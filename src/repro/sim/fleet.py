@@ -67,7 +67,7 @@ the per-sim epoch caches in the torus models are untouched and keep
 deduplicating queries before they ever reach the broker).
 
 The broker implements the ``repro.core.maskquery`` client contract,
-so installing it is one call per policy (:func:`install_mask_client`).
+so a policy takes it as ``mask_client=`` when it is built.
 
 Containment & failover (PR 9): the broker tolerates the two ways a
 fleet dies in practice.
@@ -252,8 +252,7 @@ class QueryBroker(MaskQueryClient):
     request flushes immediately: a broker is safe to use solo.
 
     ``engine`` is a registry name (``numpy``/``jax``/``pallas``/
-    ``ref``), an engine instance, or ``None`` for the registry default
-    — note the fleet path always rides an *engine*, there is no
+    ``ref``), an engine instance, or ``None`` for ``numpy`` — note the fleet path always rides an *engine*, there is no
     brokered variant of the in-torus host integral-image path (the
     numpy engine is the same arithmetic, batched).
 
@@ -289,18 +288,14 @@ class QueryBroker(MaskQueryClient):
     def __init__(self, engine=None, quorum: Optional[float] = 1.0,
                  timeout: Optional[float] = None, pad_b="auto",
                  max_inflight: int = 2):
-        from repro.core.engineconfig import (canonical_engine_name,
-                                             default_engine_name)
         from repro.kernels.fitmask import ops
         if hasattr(engine, "multibox"):
             # Custom instance: no registry identity — never failed over.
             self.engine = engine
             self.engine_name: Optional[str] = None
         else:
-            self.engine_name = (canonical_engine_name(engine)
-                                if engine is not None
-                                else default_engine_name())
-            self.engine = ops.get_engine(engine)
+            self.engine_name = ops.engine_name(engine)
+            self.engine = ops.get_engine(self.engine_name)
         self._pad_auto = pad_b == "auto"
         self.pad_b = (bool(getattr(self.engine, "pads_shapes", False))
                       if self._pad_auto else bool(pad_b))
@@ -797,27 +792,11 @@ class QueryBroker(MaskQueryClient):
             lo = hi
 
 
-def install_mask_client(policy, client) -> None:
-    """Deprecated: pass ``mask_client=`` to ``make_policy`` / the
-    policy constructor instead (constructor injection). Retained as a
-    delegating shim for callers holding an already-built policy."""
-    model = getattr(policy, "torus", None) or getattr(policy, "cluster",
-                                                      None)
-    if model is None:
-        raise TypeError(f"policy {policy!r} exposes no cluster model "
-                        "to install a mask client on")
-    import warnings
-    warnings.warn("install_mask_client is deprecated; pass mask_client= "
-                  "to make_policy/the policy constructor",
-                  DeprecationWarning, stacklevel=2)
-    model._set_mask_client(client)
-
-
 class Fleet:
     """Run a set of simulation units concurrently, sharing one broker.
 
-    Each *unit* is a callable receiving the broker (install it on your
-    policy with :func:`install_mask_client`, then run the simulation)
+    Each *unit* is a callable receiving the broker (build your policy
+    with ``mask_client=broker``, then run the simulation)
     and returning an arbitrary result. Units run on daemon threads and
     are registered with the broker *before* any of them starts, so the
     first scheduled round already coalesces across the whole fleet.

@@ -1,27 +1,14 @@
-"""EngineConfig (the single engine-selection point) and the
-constructor-injection API: precedence order, deprecated env-var alias,
-deprecated post-hoc setters, and clone propagation."""
-import warnings
-
+"""EngineConfig and the constructor-injection API: the engine is named
+where the torus is built, unnamed means numpy, ``mask_client=``
+injects a client, and clones inherit the config but not the client."""
 import pytest
 
-from repro.core import engineconfig
 from repro.core.allocator import make_policy
-from repro.core.engineconfig import EngineConfig, set_default_engine
+from repro.core.engineconfig import EngineConfig
 from repro.core.maskquery import InlineMaskClient, resolve_mask_client
 from repro.core.reconfig import ReconfigTorus
 from repro.core.torus import StaticTorus
 from repro.kernels.fitmask import ops
-from repro.sim.fleet import install_mask_client
-
-
-@pytest.fixture(autouse=True)
-def _clean_selection(monkeypatch):
-    """Each test starts from pristine process-wide selection state."""
-    monkeypatch.delenv(engineconfig.ENGINE_ENV, raising=False)
-    monkeypatch.setattr(engineconfig, "_default_engine", None)
-    monkeypatch.setattr(engineconfig, "_env_warned", False)
-    yield
 
 
 # ------------------------------------------------------------- coerce
@@ -32,53 +19,16 @@ def test_coerce_spellings():
     assert EngineConfig.coerce(cfg) is cfg
     with pytest.raises(TypeError):
         EngineConfig.coerce(42)
-
-
-# ---------------------------------------------------------- precedence
-def test_resolution_precedence(monkeypatch):
-    assert EngineConfig().resolve_name() == "numpy"  # baseline default
-    monkeypatch.setenv(engineconfig.ENGINE_ENV, "ref")
-    with warnings.catch_warnings():
-        warnings.simplefilter("ignore", DeprecationWarning)
-        assert EngineConfig().resolve_name() == "ref"     # env beats numpy
-        set_default_engine("numpy")
-        assert EngineConfig().resolve_name() == "numpy"   # programmatic beats env
-        assert EngineConfig(engine="ref").resolve_name() == "ref"  # explicit wins
-    set_default_engine(None)
+    assert EngineConfig().resolve_name() == "numpy"
+    assert EngineConfig(engine="ref").resolve_name() == "ref"
 
 
 def test_alias_folding_and_unknown_names():
-    assert EngineConfig(engine="kernel").resolve_name() == "pallas"
-    with pytest.raises(KeyError):
-        EngineConfig(engine="bogus").resolve_name()
-
-
-def test_env_var_warns_deprecation_once(monkeypatch):
-    monkeypatch.setenv(engineconfig.ENGINE_ENV, "ref")
-    with pytest.warns(DeprecationWarning, match="deprecated"):
-        EngineConfig().resolve_name()
-    with warnings.catch_warnings():
-        warnings.simplefilter("error")  # second use: silent
-        assert EngineConfig().resolve_name() == "ref"
-
-
-def test_ops_entry_points_delegate_here(monkeypatch):
-    set_default_engine("ref")
-    assert ops.default_engine_name() == "ref"
-    ops.set_default_engine(None)
-    assert engineconfig._default_engine is None
-    assert ops.default_engine_name() == "numpy"
-
-
-def test_fleet_kwargs_and_with_engine():
-    cfg = EngineConfig(engine="numpy", quorum=0.5, timeout=0.01,
-                       max_inflight=3)
-    kw = cfg.fleet_kwargs()
-    assert kw == {"engine": "numpy", "quorum": 0.5, "timeout": 0.01,
-                  "max_inflight": 3}
-    assert "max_inflight" not in EngineConfig().fleet_kwargs()
-    assert cfg.with_engine("ref").engine == "ref"
-    assert cfg.with_engine("ref").quorum == 0.5
+    for name in ("bogus", "kernel", "auto"):
+        with pytest.raises(KeyError):
+            EngineConfig(engine=name).resolve_name()
+        with pytest.raises(KeyError):
+            StaticTorus((4, 4, 4), engine=name)
 
 
 def test_mask_client_resolution():
@@ -86,7 +36,21 @@ def test_mask_client_resolution():
     assert resolve_mask_client("numpy") is None
     c = resolve_mask_client("ref")
     assert isinstance(c, InlineMaskClient)
-    assert resolve_mask_client(EngineConfig(engine="ref")) is c  # interned
+    # every inline client answers from the registry's one instance
+    assert c.engine is ops.get_engine("ref")
+    assert resolve_mask_client(EngineConfig(engine="ref")).engine is c.engine
+
+
+@pytest.mark.parametrize("cfg,fingerprint", [
+    ({}, "6a37962768e6e78d"),
+    ({"policy": "rfold", "policy_kw": {"num_xpus": 4096, "cube_n": 4},
+      "backfill": True, "engine": "pallas"}, "22598110423eebf3")])
+def test_scheduler_fingerprint_keeps_journal_names(cfg, fingerprint):
+    """The daemon's journal and snapshot names hash the engine config's
+    fields: these values were written by earlier releases, and a daemon
+    that computes another name would not find its journal."""
+    from repro.serve.scheduler import SchedulerConfig
+    assert SchedulerConfig(**cfg).fingerprint() == fingerprint
 
 
 # ------------------------------------------------- constructor injection
@@ -97,40 +61,41 @@ def test_torus_constructor_injection():
     assert t.mask_client is client
     r = ReconfigTorus(num_xpus=64, cube_n=4, engine=EngineConfig("ref"))
     assert r.engine_config.engine == "ref"
-
-
-def test_fitmask_engine_kwarg_still_accepted():
-    t = StaticTorus((4, 4, 4), fitmask_engine="ref")
-    assert t.engine_config.engine == "ref"
-    assert t.fitmask_engine == "ref"  # legacy attribute mirrors it
-
-
-def test_set_mask_client_warns_and_delegates():
-    t = StaticTorus((4, 4, 4))
-    client = InlineMaskClient("ref")
-    with pytest.warns(DeprecationWarning, match="constructor"):
-        t.set_mask_client(client)
-    assert t.mask_client is client
-    r = ReconfigTorus(num_xpus=64, cube_n=4)
-    with pytest.warns(DeprecationWarning, match="constructor"):
-        r.set_mask_client(client)
-    assert r.mask_client is client
-
-
-def test_install_mask_client_warns_and_delegates():
-    pol = make_policy("rfold", num_xpus=64, cube_n=4)
-    client = InlineMaskClient("ref")
-    with pytest.warns(DeprecationWarning):
-        install_mask_client(pol, client)
-    assert pol.cluster.mask_client is client
+    assert r.mask_client.engine is ops.get_engine("ref")
+    assert StaticTorus((4, 4, 4)).mask_client is None
+    assert ReconfigTorus(num_xpus=64, cube_n=4).mask_client is None
 
 
 def test_policy_clones_inherit_engine_config():
-    pol = make_policy("rfold", num_xpus=64, cube_n=4,
+    broker = InlineMaskClient(ops.get_engine("jax"))
+    pol = make_policy("rfold", num_xpus=64, cube_n=4, mask_client=broker,
                       engine=EngineConfig(engine="ref", fleet_size=2))
+    assert pol.cluster.mask_client is broker
     clone = pol.empty_clone()
     assert clone.cluster.engine_config == pol.cluster.engine_config
-    assert clone.cluster.mask_client is None  # probes never share clients
+    # probes never share the injected client: they take the engine's own
+    assert clone.cluster.mask_client is not broker
+    assert clone.cluster.mask_client.engine is ops.get_engine("ref")
 
     static = make_policy("folding", dims=(4, 4, 4), engine="ref")
     assert static.empty_clone().torus.engine_config.engine == "ref"
+
+
+# ------------------------------------------------- removed spellings
+def test_removed_fitmask_engine_kwarg_raises():
+    with pytest.raises(TypeError):
+        make_policy("folding", dims=(4, 4, 4), fitmask_engine="jax")
+
+
+def test_removed_alias_is_unknown():
+    with pytest.raises(KeyError):
+        ops.get_engine("auto")
+
+
+def test_removed_env_var_is_ignored(monkeypatch):
+    from repro.core.geometry import JobShape
+    monkeypatch.setenv("REPRO_FITMASK_ENGINE", "pallas")
+    pol = make_policy("folding", dims=(6, 6, 6))
+    assert pol.torus.mask_client is None             # numpy host path
+    assert pol.try_place(1, JobShape((2, 2, 2))) is not None
+    assert not pol.torus._box_masks                  # no engine cache

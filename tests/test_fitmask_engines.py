@@ -1,8 +1,8 @@
-"""Fitmask engine registry + allocator routing tests: engine
-selection (explicit / set_default_engine / env var), cross-engine
-parity on the multibox contract, the numpy engine's no-jax guarantee,
-and the placement engines (StaticTorus / ReconfigTorus / policies)
-producing identical decisions on every backend."""
+"""Fitmask engine registry + allocator routing tests: the registry's
+name rule, cross-engine parity on the multibox contract, the numpy
+engine's no-jax guarantee, and the placement engines (StaticTorus /
+ReconfigTorus / policies) producing identical decisions on every
+backend."""
 import sys
 
 import numpy as np
@@ -12,18 +12,12 @@ from hypothesis import given, settings, strategies as st
 from repro.core import fitmask as core_fitmask
 from repro.core.allocator import make_policy
 from repro.core.reconfig import ReconfigTorus
-from repro.core.torus import StaticTorus, resolve_fitmask_engine
+from repro.core.torus import StaticTorus
 from repro.kernels.fitmask import ops
 
 ENGINES = ("numpy", "jax", "pallas", "ref")
 BOXES = ((1, 1, 1), (2, 2, 2), (4, 2, 1), (3, 3, 3), (9, 1, 1),
          (8, 8, 8))
-
-
-@pytest.fixture(autouse=True)
-def _reset_default_engine():
-    yield
-    ops.set_default_engine(None)
 
 
 def _occ(seed=0, shape=(4, 8, 8, 8), p=0.35):
@@ -35,38 +29,22 @@ def test_registry_lists_all_engines():
     assert set(ENGINES) <= set(ops.available_engines())
 
 
-def test_legacy_aliases_resolve():
-    assert ops.get_engine("auto") is ops.get_engine("pallas")
-    assert ops.get_engine("kernel") is ops.get_engine("pallas")
-
-
 def test_unknown_engine_raises():
     with pytest.raises(KeyError):
         ops.get_engine("tpu-v7")
     with pytest.raises(KeyError):
-        ops.set_default_engine("nope")
+        make_policy("folding", dims=(4, 4, 4), engine="nope")
 
 
 def test_default_is_numpy():
-    assert ops.default_engine_name() == "numpy"
-    assert resolve_fitmask_engine(None) is None
-
-
-def test_env_var_selects_default(monkeypatch):
-    monkeypatch.setenv(ops.ENGINE_ENV, "jax")
-    assert ops.default_engine_name() == "jax"
-    assert resolve_fitmask_engine(None) is ops.get_engine("jax")
-    monkeypatch.setenv(ops.ENGINE_ENV, "bogus")
-    with pytest.raises(KeyError):
-        ops.default_engine_name()
-
-
-def test_set_default_engine_overrides_env(monkeypatch):
-    monkeypatch.setenv(ops.ENGINE_ENV, "ref")
-    ops.set_default_engine("pallas")
-    assert ops.default_engine_name() == "pallas"
-    ops.set_default_engine(None)
-    assert ops.default_engine_name() == "ref"
+    """An unnamed selection is the numpy registry engine, and a
+    default-built policy takes the host path with no client."""
+    from repro.core.geometry import JobShape
+    assert ops.get_engine() is ops.get_engine("numpy")
+    pol = make_policy("folding", dims=(6, 6, 6))
+    assert pol.torus.mask_client is None
+    assert pol.try_place(1, JobShape((2, 2, 2))) is not None
+    assert pol.torus._fit_ii is not None       # host integral image
 
 
 # ------------------------------------------------------- parity
@@ -231,7 +209,7 @@ def test_static_torus_engine_parity():
     randomly occupied torus, with and without prefetch."""
     rng = np.random.default_rng(3)
     boxes = [(2, 2, 2), (4, 1, 1), (3, 2, 2), (8, 8, 8), (2, 4, 2)]
-    toruses = {name: StaticTorus((8, 8, 8), fitmask_engine=name)
+    toruses = {name: StaticTorus((8, 8, 8), engine=name)
                for name in ENGINES}
     mask = rng.uniform(size=(8, 8, 8)) < 0.4
     for t in toruses.values():
@@ -249,7 +227,7 @@ def test_static_torus_engine_parity():
 
 def test_static_torus_engine_epoch_invalidation():
     """Engine-cached masks refresh when occupancy changes."""
-    t = StaticTorus((6, 6, 6), fitmask_engine="pallas")
+    t = StaticTorus((6, 6, 6), engine="pallas")
     assert t.find_free_box((2, 2, 2)) == (0, 0, 0)
     t.commit_box(1, (0, 0, 0), (2, 2, 2))
     origin = t.find_free_box((2, 2, 2))
@@ -264,7 +242,7 @@ def test_reconfig_block_free_engine_parity():
     rng = np.random.default_rng(4)
     locals_ = [((0, 2), (0, 2), (0, 2)), ((1, 4), (0, 4), (2, 3)),
                ((0, 4), (0, 4), (0, 4)), ((3, 4), (3, 4), (3, 4))]
-    rts = {name: ReconfigTorus(512, 4, fitmask_engine=name)
+    rts = {name: ReconfigTorus(512, 4, engine=name)
            for name in ENGINES}
     mask = rng.uniform(size=(8, 4, 4, 4)) < 0.3
     for rt in rts.values():
@@ -297,7 +275,7 @@ def test_engine_runs_build_no_host_integral_image(engine, monkeypatch):
     monkeypatch.setattr(core_fitmask, "batched_integral_image", _poisoned)
     for policy in ("reconfig", "rfold"):
         pol = make_policy(policy, num_xpus=256, cube_n=4,
-                          fitmask_engine=engine)
+                          engine=engine)
         assert pol.try_place(1, JobShape((4, 4, 2))) is not None
         assert pol.try_place(2, JobShape((8, 2, 2))) is not None
         pol.release(1)
@@ -318,7 +296,7 @@ def test_policy_engine_parity_small_sim():
                        ("rfold", dict(num_xpus=512, cube_n=4))):
         base = None
         for name in ENGINES:
-            pol = make_policy(policy, fitmask_engine=name, **kw)
+            pol = make_policy(policy, engine=name, **kw)
             s = summarize(Simulator(pol, list(jobs)).run())
             if base is None:
                 base = s
@@ -326,17 +304,29 @@ def test_policy_engine_parity_small_sim():
                 assert s == base, (policy, name)
 
 
-def test_policy_engine_from_env(monkeypatch):
-    """REPRO_FITMASK_ENGINE routes a default-constructed policy's
-    placement queries through the named engine."""
+@pytest.mark.parametrize("policy,kw", [
+    ("folding", dict(dims=(6, 6, 6))),
+    ("rfold", dict(num_xpus=128, cube_n=4))])
+def test_placement_calls_the_engine_singleton(policy, kw):
+    """The benchmark's contract: a method set on the registry's engine
+    instance after the policy is built (as span instrumentation does)
+    is what the policy's next placement calls."""
     from repro.core.geometry import JobShape
-    monkeypatch.setenv(ops.ENGINE_ENV, "pallas")
-    pol = make_policy("folding", dims=(6, 6, 6))
-    assert pol.torus.fitmask_engine is None
-    assert resolve_fitmask_engine(None) is ops.get_engine("pallas")
-    assert pol.try_place(1, JobShape((2, 2, 2))) is not None
-    # the placement actually consulted the engine-side mask cache
-    assert pol.torus._box_masks
+    pol = make_policy(policy, engine="jax", **kw)
+    eng = ops.get_engine("jax")
+    calls = []
+    real = eng.multibox
+
+    def counted(occ, boxes):
+        calls.append(len(boxes))
+        return real(occ, boxes)
+
+    eng.multibox = counted
+    try:
+        assert pol.try_place(1, JobShape((2, 2, 2))) is not None
+    finally:
+        del eng.multibox
+    assert calls
 
 
 if __name__ == "__main__":

@@ -14,7 +14,7 @@ from repro.eval import EvalRunner, make_tasks
 from repro.eval.runner import (iter_checkpoints, make_fleet_chunks,
                                run_fleet_tasks, task_grid_bucket)
 from repro.kernels.fitmask import ops
-from repro.sim.fleet import Fleet, QueryBroker, install_mask_client
+from repro.sim.fleet import Fleet, QueryBroker
 
 # Small matrix covering both cluster models and two grid cell shapes.
 CONFIGS = [
@@ -288,11 +288,6 @@ def test_fleet_surfaces_unit_exception():
 
     with pytest.raises(ValueError, match="sim exploded"):
         Fleet("numpy").run([bad, good])
-
-
-def test_install_mask_client_requires_cluster_model():
-    with pytest.raises(TypeError):
-        install_mask_client(object(), QueryBroker("numpy"))
 
 
 # ---------------------------------------------------- fleet-of-sims

@@ -249,7 +249,7 @@ def test_fitmask_batched_cubes_use_case():
     rng = np.random.default_rng(0)
     cubes = rng.uniform(size=(64, 4, 4, 4)) < 0.4
     box = (4, 2, 1)
-    out = np.asarray(fit_ops.fitmask(jnp.array(cubes), box, engine="kernel"))
+    out = np.asarray(fit_ops.fitmask(jnp.array(cubes), box, engine="pallas"))
     for i in range(64):
         brute = np.zeros((4, 4, 4), np.int32)
         for y in range(3):

@@ -47,7 +47,7 @@ def calls(monkeypatch):
 def _torus(client=None, seed=0):
     """A pallas torus of 8 cubes with three sub-block shapes seen and
     their masks cached (one full refresh done)."""
-    rt = ReconfigTorus(512, 4, fitmask_engine="pallas", mask_client=client)
+    rt = ReconfigTorus(512, 4, engine="pallas", mask_client=client)
     rt.occ[:] = _occ(seed)
     rt.bump_epoch()
     for local in LOCALS:
@@ -97,7 +97,7 @@ def test_full_refresh_with_cached_shapes_is_one_device_call(calls):
 
 
 def test_first_refresh_with_no_shapes_asks_only_the_counts(calls):
-    rt = ReconfigTorus(512, 4, fitmask_engine="pallas")
+    rt = ReconfigTorus(512, 4, engine="pallas")
     rt.occ[:] = _occ(3)
     rt.bump_epoch()
     rt._derived()

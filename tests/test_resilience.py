@@ -530,7 +530,7 @@ def test_engine_failure_mid_run_schedules_match_host_oracle():
     computed against the host oracle from the start."""
     from repro.api import (Simulator, TraceConfig, generate_trace,
                            make_policy)
-    from repro.sim.fleet import Fleet, install_mask_client
+    from repro.sim.fleet import Fleet
 
     cfg = TraceConfig(num_jobs=40, cluster_xpus=512, size_max=512,
                       seed=5)
@@ -546,8 +546,7 @@ def test_engine_failure_mid_run_schedules_match_host_oracle():
     fleet.broker.inject_engine_faults(2)
 
     def unit(broker):
-        policy = make_policy("rfold", **MEDIUM)
-        install_mask_client(policy, broker)
+        policy = make_policy("rfold", mask_client=broker, **MEDIUM)
         return Simulator(policy, generate_trace(cfg)).run()
 
     (res,) = fleet.run([unit])
