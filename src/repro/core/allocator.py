@@ -18,6 +18,8 @@ from __future__ import annotations
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Tuple
 
+import numpy as np
+
 from repro import obs
 
 from .folding import Fold, enumerate_folds, fold_links, verify_fold
@@ -97,6 +99,42 @@ class PlacementPolicy:
 # Static-torus policies
 # ----------------------------------------------------------------------
 
+def _commit_template(fold: Fold, dims: Dims, torus_wrap: Tuple[bool, ...],
+                     box_wrap: Tuple[bool, ...]):
+    """``fold`` placed at the origin of a static torus: its physical
+    links, canonically oriented, and the flat occupancy index of each
+    XPU in ``fold.mapping`` order; and whether they were memoized.
+
+    A link is a ring edge that is a torus link and is direct or a wrap
+    link of an axis the box spans in full (``box_wrap``); a wrap edge the
+    box cannot close consumes no link. None of this depends on where the
+    box sits: the box never wraps past the torus boundary, so a
+    translation keeps every delta and ``canon_link``'s order. Memoized
+    per fold instance (folds are immutable and shared, see
+    ``verify_fold``); two threads that build one template at once build
+    equal values."""
+    cache = getattr(fold, "_commit_cache", None)
+    if cache is None:
+        cache = {}
+        object.__setattr__(fold, "_commit_cache", cache)
+    key = (dims, tuple(torus_wrap), tuple(box_wrap))
+    hit = cache.get(key)
+    if hit is not None:
+        return hit, True
+    links = []
+    for (u, v) in fold_links(fold, (0, 0, 0), dims):
+        if is_torus_neighbor(u, v, dims, torus_wrap):
+            direct = all(abs(a - b) <= 1 for a, b in zip(u, v))
+            if direct or any(box_wrap[ax] and abs(u[ax] - v[ax]) == dims[ax] - 1
+                             for ax in range(3)):
+                links.append(canon_link(u, v))
+    m = np.asarray(fold.mapping, dtype=np.intp).reshape(-1, 3)
+    cells = np.ravel_multi_index(m.T, dims)
+    hit = (tuple(links), cells)
+    cache[key] = hit
+    return hit, False
+
+
 class _StaticBase(PlacementPolicy):
     def __init__(self, dims: Dims = (16, 16, 16), engine=None,
                  mask_client=None):
@@ -131,41 +169,35 @@ class _StaticBase(PlacementPolicy):
         return tuple(b == d for b, d in zip(box, self.torus.dims))
 
     def _commit_fold(self, job_id: int, fold: Fold, origin: Coord,
-                     broken: Tuple[int, ...]) -> Placement:
-        coords = []
-        d0, d1, d2 = fold.job_dims
-        for i in range(d0):
-            for j in range(d1):
-                for k in range(d2):
-                    e = fold.embed((i, j, k))
-                    coords.append(tuple(o + v for o, v in zip(origin, e)))
-        # Links: ring edges that are physically realizable (direct or via
-        # an available wrap link); broken closures consume no link. A
-        # cut link (chaos layer) cannot be claimed — the ring routes
-        # around it, so its axis joins the broken set (same 17% slowdown
-        # the paper charges any broken ring).
-        wrap = self._wrap_for_box(fold.box, origin)
-        links = []
-        cut = self.torus.cut_links
-        extra_broken: set = set()
-        for (u, v) in fold_links(fold, origin, self.torus.dims):
-            if is_torus_neighbor(u, v, self.torus.dims, self.torus.wrap_flags()):
-                # physical only if inside box or via full-span wrap
-                direct = all(abs(a - b) <= 1 for a, b in zip(u, v))
-                if direct or any(
-                        wrap[ax] and abs(u[ax] - v[ax]) == self.torus.dims[ax] - 1
-                        for ax in range(3)):
-                    l = canon_link(u, v)
-                    if cut and l in cut:
-                        extra_broken.add(next(
-                            ax for ax in range(3) if u[ax] != v[ax]))
-                    else:
-                        links.append(l)
-        if extra_broken:
-            broken = tuple(sorted(set(broken) | extra_broken))
+                     broken: Tuple[int, ...], sp) -> Placement:
+        """Commit ``fold`` at ``origin`` from its origin-free template
+        (:func:`_commit_template`): only the origin is added here. A cut
+        link (chaos layer) cannot be claimed: the ring routes around it,
+        so its axis joins the broken set (the same 17% slowdown the
+        paper charges any broken ring). Tags ``sp`` (the caller's
+        ``plan.search``) with ``commits`` and ``commit_memo``."""
+        torus = self.torus
+        (links, cells), memo = _commit_template(
+            fold, torus.dims, torus.wrap_flags(),
+            self._wrap_for_box(fold.box, origin))
+        ox, oy, oz = origin
+        coords = [(ox + x, oy + y, oz + z) for x, y, z in fold.mapping]
+        links = [((ox + a, oy + b, oz + c), (ox + d, oy + e, oz + f))
+                 for (a, b, c), (d, e, f) in links]
+        cells = cells + (ox * torus.dims[1] + oy) * torus.dims[2] + oz
+        cut = torus.cut_links
+        if cut:
+            severed = [l for l in links if l in cut]
+            if severed:
+                links = [l for l in links if l not in cut]
+                broken = tuple(sorted(set(broken).union(
+                    next(ax for ax in range(3) if u[ax] != v[ax])
+                    for u, v in severed)))
         meta = {"fold": str(fold), "kind": fold.kind, "box": fold.box,
                 "origin": origin, "broken_rings": broken}
-        self.torus.commit(job_id, coords, links, meta)
+        torus.commit(job_id, coords, links, meta, cells=cells)
+        if sp.recording:
+            sp.tag(commits=1, commit_memo=int(memo))
         return Placement(job_id, JobShape(fold.job_dims), broken, meta)
 
 
@@ -203,7 +235,7 @@ class FirstFitPolicy(_StaticBase):
                 if not ok:
                     continue
                 placement = self._commit_fold(job_id, fold, origin,
-                                              tuple(broken))
+                                              tuple(broken), sp)
                 break
             if sp.recording:
                 sp.tag(policy=self.name, folds=visited,
@@ -248,7 +280,7 @@ class FoldingPolicy(_StaticBase):
                 return None
             candidates.sort(key=lambda t: t[0])
             _, fold, origin, broken = candidates[0]
-            return self._commit_fold(job_id, fold, origin, broken)
+            return self._commit_fold(job_id, fold, origin, broken, sp)
 
 
 # ----------------------------------------------------------------------

@@ -48,12 +48,16 @@ class Allocation:
                   folded ring placements: it is the ring traversal).
     ``links``   — torus links owned by the job.
     ``meta``    — provenance: fold used, target box, cubes touched, etc.
+    ``cells``   — the flat index of each of ``coords`` in the torus's
+                  occupancy grid (what ``release`` clears).
     """
 
     job_id: int
     coords: Tuple[Coord, ...]
     links: FrozenSet[Link]
     meta: dict = field(default_factory=dict)
+    cells: Optional[np.ndarray] = field(default=None, compare=False,
+                                        repr=False)
 
     @property
     def size(self) -> int:
@@ -280,28 +284,38 @@ class StaticTorus:
 
     # ------------------------------------------------------------------
     def commit(self, job_id: int, coords: Sequence[Coord],
-               links: Iterable[Link], meta: Optional[dict] = None) -> Allocation:
+               links: Iterable[Link], meta: Optional[dict] = None,
+               cells: Optional[np.ndarray] = None) -> Allocation:
+        """Give ``job_id`` the XPUs ``coords`` and the ``links``; refuse
+        (ValueError, naming the first offender) duplicate or owned XPUs
+        and owned or cut links, changing nothing. ``cells``: the flat
+        occupancy index of each XPU of ``coords``, where the caller
+        already has them (a placed fold's template)."""
         coords = tuple(coords)
         links = frozenset(links)
         if len(set(coords)) != len(coords):
             raise ValueError("duplicate XPUs in allocation")
-        for c in coords:
-            if self.occ[c]:
-                raise ValueError(f"XPU {c} already owned by {self.owner[c]}")
-        for l in links:
-            if l in self.link_owner:
-                raise ValueError(
-                    f"link {l} already owned by job {self.link_owner[l]}")
-            if l in self.cut_links:
-                raise ValueError(f"link {l} is cut (fault injected)")
-        for c in coords:
-            self.occ[c] = True
-            self.owner[c] = job_id
-        for l in links:
-            self.link_owner[l] = job_id
+        if cells is None:
+            cells = np.ravel_multi_index(
+                np.array(coords, dtype=np.intp).reshape(-1, 3).T, self.dims)
+        taken = self.occ.take(cells)
+        if taken.any():
+            c = coords[int(taken.argmax())]
+            raise ValueError(f"XPU {c} already owned by {self.owner[c]}")
+        if not (self.link_owner.keys().isdisjoint(links)
+                and self.cut_links.isdisjoint(links)):
+            for l in links:
+                if l in self.link_owner:
+                    raise ValueError(
+                        f"link {l} already owned by job {self.link_owner[l]}")
+                if l in self.cut_links:
+                    raise ValueError(f"link {l} is cut (fault injected)")
+        self.occ.put(cells, True)
+        self.owner.put(cells, job_id)
+        self.link_owner.update(dict.fromkeys(links, job_id))
         self._epoch += 1
         self._busy += len(coords)
-        alloc = Allocation(job_id, coords, links, dict(meta or {}))
+        alloc = Allocation(job_id, coords, links, dict(meta or {}), cells)
         self.allocations[job_id] = alloc
         if self.listeners:
             _events.emit(self.listeners, _events.TopologyEvent(
@@ -320,9 +334,8 @@ class StaticTorus:
 
     def release(self, job_id: int) -> None:
         alloc = self.allocations.pop(job_id)
-        for c in alloc.coords:
-            self.occ[c] = False
-            self.owner[c] = -1
+        self.occ.put(alloc.cells, False)
+        self.owner.put(alloc.cells, -1)
         for l in alloc.links:
             del self.link_owner[l]
         self._epoch += 1
