@@ -36,7 +36,10 @@ log — a standby's cursor is just a journal index, served as WAL-framed
 bytes by :meth:`AllocatorCore.journal_frames` and applied on the
 standby via :meth:`AllocatorCore.apply_replicated` (replay-mode apply
 + append to the standby's *own* WAL, so a promoted standby recovers
-like any primary). Leadership is fenced by a monotonic ``epoch``
+like any primary). A standby follows any primary whose
+:meth:`SchedulerConfig.stream_id` equals its own: the engine is left
+out of it, so a ``numpy`` standby tails a ``pallas`` primary, while
+each keeps its checkpoint under its own fingerprint. Leadership is fenced by a monotonic ``epoch``
 stamped on every journal record (``"e"``): promotion journals a
 ``promote`` op carrying the new epoch, so the fencing token survives
 recovery and replication by the same mechanism as everything else.
@@ -62,6 +65,11 @@ from repro.sim.faults import FaultEvent, FaultInjector
 
 from . import protocol
 from .journal import JournalWriter, encode_frames, recover_journal
+
+
+def _hash16(fields: Dict[str, Any]) -> str:
+    blob = json.dumps(fields, sort_keys=True, default=str)
+    return hashlib.sha256(blob.encode()).hexdigest()[:16]
 
 
 @dataclass
@@ -132,20 +140,33 @@ class SchedulerConfig:
 
     # -- checkpoint-store duck-type (repro.eval.runner) ----------------
     def fingerprint(self) -> str:
-        """Hash of every field that affects placement outcomes. The
-        transport fields (host/port), checkpoint cadence and the
+        """Hash of every field that affects placement outcomes, the
+        fitmask engine included: it names the checkpoint and its WAL.
+        The transport fields (host/port), checkpoint cadence and the
         resilience knobs (fsync, leases, dedup, backpressure,
         role/replication/ack mode) are excluded: moving the daemon,
         retuning snapshot frequency or lease policy, or promoting a
         standby must not orphan its journal — lease expiries are
         journaled with their *resolved* action, so replay never
-        consults the current lease_policy, and a primary and its
-        standby share one fingerprint (the replication stream id)."""
-        fields = {"policy": self.policy, "policy_kw": self.policy_kw,
-                  "backfill": self.backfill, "max_queue": self.max_queue,
-                  "engine": asdict(self.engine)}
-        blob = json.dumps(fields, sort_keys=True, default=str)
-        return hashlib.sha256(blob.encode()).hexdigest()[:16]
+        consults the current lease_policy. The replication stream is
+        checked by :meth:`stream_id`, not by this."""
+        return _hash16({**self._placement_fields(),
+                        "engine": asdict(self.engine)})
+
+    def stream_id(self) -> str:
+        """Id of the replication stream: a hash of what decides a
+        placement (policy, its arguments, backfill, the queue cap) and
+        nothing else. Every engine must answer what the plain rule
+        answers, so a standby on another engine (a control-plane host
+        without an accelerator runs ``numpy``) applies the primary's
+        log as is; a standby with another policy, ``policy_kw``,
+        backfill or queue cap would place differently and is
+        refused."""
+        return _hash16(self._placement_fields())
+
+    def _placement_fields(self) -> Dict[str, Any]:
+        return {"policy": self.policy, "policy_kw": self.policy_kw,
+                "backfill": self.backfill, "max_queue": self.max_queue}
 
     def checkpoint_name(self) -> str:
         return f"scheduler_{self.policy}__r0__{self.fingerprint()}.json"

@@ -42,6 +42,22 @@ journal. In ``ack_mode="sync"`` the primary holds each journaled-op
 reply until the standby's piggybacked ``acked`` cursor covers the
 record (bounded by ``sync_timeout``), so an acked op survives even
 primary disk loss.
+
+The replication stream is named by ``SchedulerConfig.stream_id()``,
+which hashes only what decides a placement (policy, its arguments,
+backfill, the queue cap): a pull whose id differs is refused, so a
+standby never applies a log that would place differently under its
+own rules. The fitmask engine is not part of it, since every engine
+answers what the plain rule answers: a ``numpy`` standby on a host
+without an accelerator tails a ``pallas`` primary. The checkpoint and
+its WAL keep the name ``fingerprint()`` gives, engine included, each
+replica in its own store.
+
+Spans (``repro.obs``, recorded under a profiler session only):
+``daemon.sync_wait`` times a sync-mode op's wait for the standby's
+ack; ``repl.serve`` times the primary's framing of one pull's records
+and carries the standby's own apply time, which each pull reports as
+``apply_s`` since the standby's process records no spans.
 """
 from __future__ import annotations
 
@@ -132,7 +148,6 @@ class SchedulerDaemon:
         await self._closing.wait()
         if self._server is not None:
             self._server.close()
-            await self._server.wait_closed()
         if self._lease_task is not None:
             self._lease_task.cancel()
         if self._repl_task is not None:
@@ -140,8 +155,14 @@ class SchedulerDaemon:
         for sub in list(self._subscribers.values()):
             if sub.task is not None:
                 sub.task.cancel()
+        # Drop every connection before waiting for the server, which
+        # (Python 3.12 on) waits for them: a peer that sends nothing,
+        # such as a stopped standby, would otherwise keep a killed
+        # daemon serving.
         for w in list(self._writers):
             w.close()
+        if self._server is not None:
+            await self._server.wait_closed()
         if self.mask_client is not None \
                 and hasattr(self.mask_client, "deactivate"):
             self.mask_client.deactivate()
@@ -317,6 +338,13 @@ class SchedulerDaemon:
         live follower (none ever pulled, or silent for longer than
         sync_timeout — e.g. right after a promotion) degrade
         immediately: availability over a wait nobody will satisfy."""
+        with obs.span("daemon.sync_wait", target=target) as sp:
+            ok = await self._wait_replicated(target)
+            if sp.recording:
+                sp.tag(ok=ok)
+        return ok
+
+    async def _wait_replicated(self, target: int) -> bool:
         if self._follower_acked >= target:
             return True
         if (self._last_pull is None
@@ -377,14 +405,18 @@ class SchedulerDaemon:
         """Serve the replication stream: WAL-framed records from the
         follower's journal-index cursor. ``wait`` long-polls until a
         record lands (bounded by repl_poll); ``acked`` piggybacks the
-        follower's durable length for sync ack mode."""
-        fp = self.core.config.fingerprint()
-        if msg.get("fingerprint") not in (None, fp):
-            return {"ok": False, "error": "fingerprint mismatch",
-                    "fingerprint": fp}
+        follower's durable length for sync ack mode, ``apply_s`` the
+        seconds it took to apply and fsync the previous reply's
+        records. A pull on another stream (``stream``) is refused."""
+        sid = self.core.config.stream_id()
+        if msg.get("stream") not in (None, sid):
+            return {"ok": False, "error": "stream mismatch",
+                    "stream": sid}
         self._last_pull = time.monotonic()
+        acked_before = self._follower_acked
         if msg.get("acked") is not None:
             self._note_acked(int(msg["acked"]))
+        acked_delta = self._follower_acked - acked_before
         index = int(msg.get("index", 0))
         if index > len(self.core.journal):
             # A follower ahead of us is tailing someone else's log
@@ -397,8 +429,14 @@ class SchedulerDaemon:
                 await asyncio.wait_for(ev.wait(), self.config.repl_poll)
             except asyncio.TimeoutError:
                 pass
-        frames, nxt = self.core.journal_frames(index)
-        return {"ok": True, "fingerprint": fp, "index": index,
+        with obs.span("repl.serve") as sp:
+            frames, nxt = self.core.journal_frames(index)
+            if sp.recording:
+                sp.tag(records=nxt - index, bytes=len(frames),
+                       acked_delta=acked_delta)
+                if msg.get("apply_s") is not None:
+                    sp.tag(standby_apply_s=float(msg["apply_s"]))
+        return {"ok": True, "stream": sid, "index": index,
                 "next": nxt, "journal_len": len(self.core.journal),
                 "role": self.role,
                 "frames": base64.b64encode(frames).decode("ascii")}
@@ -409,11 +447,14 @@ class SchedulerDaemon:
         durable-ack (our core fsyncs each applied record to its own
         WAL before the next pull), applies every intact frame, and
         reconnects with backoff across primary restarts — a dead
-        primary leaves the standby warm and promotable, not crashed."""
+        primary leaves the standby warm and promotable, not crashed.
+        Each pull reports how long the previous reply's records took
+        to apply (``apply_s``)."""
         host, port = self.config.replicate_from
-        fp = self.core.config.fingerprint()
+        sid = self.core.config.stream_id()
         backoff = 0.05
         seq = 0
+        apply_s: Optional[float] = None
         read_timeout = self.config.repl_poll + 5.0
         while not self._closing.is_set():
             try:
@@ -427,12 +468,13 @@ class SchedulerDaemon:
             try:
                 while not self._closing.is_set():
                     seq += 1
-                    writer.write(protocol.encode(
-                        {"op": "repl_pull", "seq": seq,
-                         "fingerprint": fp,
-                         "index": len(self.core.journal),
-                         "acked": len(self.core.journal),
-                         "wait": True}))
+                    pull = {"op": "repl_pull", "seq": seq, "stream": sid,
+                            "index": len(self.core.journal),
+                            "acked": len(self.core.journal),
+                            "wait": True}
+                    if apply_s is not None:
+                        pull["apply_s"] = apply_s
+                    writer.write(protocol.encode(pull))
                     await writer.drain()
                     line = await asyncio.wait_for(reader.readline(),
                                                   read_timeout)
@@ -447,10 +489,12 @@ class SchedulerDaemon:
                     if torn:
                         self.last_repl_error = "torn frame in pull reply"
                         break   # reconnect and re-pull from our cursor
+                    t0 = time.perf_counter()
                     for rec in records:
                         if rec.get("i") != len(self.core.journal):
                             break   # gap/overlap: re-pull from cursor
                         self.core.apply_replicated(rec)
+                    apply_s = time.perf_counter() - t0
                     self.repl_lag = max(
                         0, int(resp.get("journal_len", 0))
                         - len(self.core.journal))

@@ -30,7 +30,7 @@ Replication (PR 10): the framing doubles as the **over-the-wire
 replication format**. :func:`encode_frames` / :func:`decode_frames`
 are the pure-bytes halves of the writer/recovery above — the primary
 daemon answers a follower's cursor with a run of framed records (no
-MAGIC; the stream id travels as the config fingerprint instead), and
+MAGIC; the stream id, ``SchedulerConfig.stream_id``, travels instead), and
 the follower applies every frame that checks out, ignoring a torn
 tail exactly as crash recovery would. One format, one parser, one set
 of torn-tail semantics for disk and wire.

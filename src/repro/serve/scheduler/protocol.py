@@ -54,7 +54,7 @@ Replication & fencing fields (PR 10):
 
 Replication ops:
 
-  ``repl_pull``       fingerprint, index, acked, wait — a follower's
+  ``repl_pull``       stream, index, acked, wait, apply_s — a follower's
                       cursor into the leader's op log. The reply holds
                       ``frames`` (base64 of WAL-framed records from
                       ``index``; the PR 9 on-disk framing *is* the
@@ -66,8 +66,15 @@ Replication ops:
                       ``wait`` long-polls: the reply is deferred until
                       new records exist (or a timeout), so a warm
                       standby tails record-for-record without busy
-                      polling. A fingerprint mismatch is refused: a
-                      follower must never apply another config's log.
+                      polling. ``apply_s`` reports the seconds the
+                      follower spent applying and fsyncing the
+                      previous reply's records. A ``stream`` id
+                      (``SchedulerConfig.stream_id``: policy, its
+                      arguments, backfill, queue cap) that differs
+                      from the leader's is refused: a follower must
+                      never apply a log its own rules would place
+                      differently. The fitmask engine is not part of
+                      it.
   ``promote``         mint a new fencing epoch (old + 1, journaled) and
                       become leader. On a standby this stops the
                       replication tail first; the promotion record is

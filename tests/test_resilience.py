@@ -818,7 +818,7 @@ def test_config_validates_replication_fields():
     with pytest.raises(ValueError, match="replicate_from"):
         SchedulerConfig(role="standby")
     # Replication knobs never change the checkpoint identity: a
-    # standby shares the primary's fingerprint (the stream id).
+    # standby on the primary's engine shares its fingerprint.
     a = SchedulerConfig(policy="rfold", policy_kw=MEDIUM)
     b = SchedulerConfig(policy="rfold", policy_kw=MEDIUM,
                         role="standby", replicate_from=("h", 1),
